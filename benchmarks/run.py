@@ -24,6 +24,8 @@ import json
 import sys
 import time
 
+from repro.compile_cache import use_compile_cache
+
 MODULES = [
     "exp0_paper_example",
     "exp1_slr_speedup",
@@ -94,6 +96,7 @@ def main() -> None:
                          "engine-vs-reference speedup probe)")
     args = ap.parse_args()
     only = [x.strip() for x in args.only.split(",") if x.strip()]
+    use_compile_cache()
 
     all_rows = []
     print("name,us_per_call,derived")

@@ -101,6 +101,7 @@ dispatch, fetch) on the scan path and O(levels) on the per-wave path.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 import time
@@ -188,6 +189,13 @@ def _use_scan() -> bool:
     return True
 
 
+def _x64(f32: bool):
+    """Scope for one f64 upload/dispatch: without ``jax.enable_x64``
+    jnp and jit canonicalize float64 inputs (and the kernel trace) down
+    to float32.  The f32 path needs no switch."""
+    return contextlib.nullcontext() if f32 else jax.enable_x64(True)
+
+
 def _bucket(b: int) -> int:
     """Smallest power of two >= b (bounds compiled kernel variants)."""
     n = 1
@@ -248,7 +256,7 @@ def _batch_kernel(alpha_ref, period_ref, aft_ref, ct_ref, masks_ref,
     lane = jnp.broadcast_to(lf, (P, L))
     arrival = jnp.full((P,), _NEG_INF, dtype=f)
     for k in range(K):
-        aft_i = aft_ref[0, k]
+        aft_i = aft_ref[0, 0, k]
         r_lst = []
         r_lft = []
         r_final = []
@@ -299,9 +307,10 @@ def _batch_kernel(alpha_ref, period_ref, aft_ref, ct_ref, masks_ref,
 
     # ---- batched Eqs. 10-12 + Defs. 4.1-4.2 over all P lanes ----
     est = jnp.maximum(arrival, pf)                       # Eqs. 10-11
-    eft = est + comp_ref[0]                              # Eq. 12
-    a = eft * ldet_ref[0]
-    is_exit = flags_ref[0, 0] > 0
+    comp = comp_ref[0, 0]
+    eft = est + comp                                     # Eq. 12
+    a = eft * ldet_ref[0, 0]
+    is_exit = flags_ref[0, 0, 0] > 0
     value = a * jnp.where(is_exit, one, bp)  # Def. 4.1 (exit: ldet=bp=1)
     # strict lexicographic (value, eft, proc) argmin, first-index ties
     vmin = jnp.min(value)
@@ -309,13 +318,13 @@ def _batch_kernel(alpha_ref, period_ref, aft_ref, ct_ref, masks_ref,
     emin = jnp.min(jnp.where(tie, eft, _INF))
     tie &= eft == emin
     w = jnp.min(jnp.where(tie, idx, jnp.int32(P)))
-    win_ref[0] = w
-    est_ref[0, :] = est
-    eft_ref[0, :] = eft
-    a_ref[0, :] = a
-    b_ref[0, :] = a * lop            # pre-commit loads/period, as scalar
+    win_ref[0, 0, :] = jnp.broadcast_to(w, (1,))
+    est_ref[0, 0, :] = est
+    eft_ref[0, 0, :] = eft
+    a_ref[0, 0, :] = a
+    b_ref[0, 0, :] = a * lop         # pre-commit loads/period, as scalar
     # ---- in-kernel commit (the next grid step reads this state) ----
-    real = flags_ref[0, 1] > 0
+    real = flags_ref[0, 0, 1] > 0
     onehot = (idx == w) & real
     # the winner lane's column of the lane buffer IS the committed
     # link state: masked overwrites only ever raise (LFT >= avail),
@@ -323,7 +332,7 @@ def _batch_kernel(alpha_ref, period_ref, aft_ref, ct_ref, masks_ref,
     win_col = jnp.max(jnp.where(onehot[:, None], lane, neg), axis=0)
     lf_ref[:] = jnp.where(real, win_col, lf)
     pf_ref[:] = jnp.where(onehot, eft, pf)
-    loads = jnp.where(onehot, loads + comp_ref[0], loads)
+    loads = jnp.where(onehot, loads + comp, loads)
     loads_ref[:] = loads
     lop = jnp.where(onehot, loads / period, lop)
     lop_ref[:] = lop
@@ -341,29 +350,32 @@ def _compiled_run(B: int, K: int, R: int, H: int, P: int, L: int,
         full = lambda *shape: pl.BlockSpec(shape, lambda i: (0,) * len(shape))  # noqa: E731
         dec = lambda *shape: pl.BlockSpec((1,) + shape,  # noqa: E731
                                           lambda i: (i,) + (0,) * len(shape))
+        # Mosaic blocks must match the array in their last two dims (or
+        # tile them): per-decision rows of rank < 2 get a unit axis, so
+        # a (B, 1, X) array is blocked (1, 1, X)
         in_specs = [
             full(1), full(1),                        # alpha, period
-            dec(K),                                  # aft
+            dec(1, K),                               # aft
             dec(K, R, H, P),                         # ct
             dec(K, R, H, P, L),                      # masks
             dec(K, R, P), dec(K, R, P),              # valid, nhops
-            dec(P), dec(P),                          # comp, ldet
-            dec(2),                                  # (is_exit, is_real)
+            dec(1, P), dec(1, P),                    # comp, ldet
+            dec(1, 2),                               # (is_exit, is_real)
             full(L), full(P), full(P), full(P), full(P),   # state in
         ]
         out_specs = (
-            dec(),                                   # winner lane
-            dec(P), dec(P), dec(P), dec(P),          # est, eft, A, B
+            dec(1, 1),                               # winner lane
+            dec(1, P), dec(1, P), dec(1, P), dec(1, P),    # est, eft, A, B
             dec(K, H, P), dec(K, H, P),              # selected LST/LFT
             dec(K, P),                               # selected route
             full(L), full(P), full(P), full(P), full(P),   # state carry
         )
         out_shape = (
-            jax.ShapeDtypeStruct((B,), i32),         # winner lane
-            jax.ShapeDtypeStruct((B, P), f),         # est
-            jax.ShapeDtypeStruct((B, P), f),         # eft
-            jax.ShapeDtypeStruct((B, P), f),         # cand_A
-            jax.ShapeDtypeStruct((B, P), f),         # cand_B
+            jax.ShapeDtypeStruct((B, 1, 1), i32),    # winner lane
+            jax.ShapeDtypeStruct((B, 1, P), f),      # est
+            jax.ShapeDtypeStruct((B, 1, P), f),      # eft
+            jax.ShapeDtypeStruct((B, 1, P), f),      # cand_A
+            jax.ShapeDtypeStruct((B, 1, P), f),      # cand_B
             jax.ShapeDtypeStruct((B, K, H, P), f),   # selected LST
             jax.ShapeDtypeStruct((B, K, H, P), f),   # selected LFT
             jax.ShapeDtypeStruct((B, K, P), i32),    # selected route
@@ -383,8 +395,11 @@ def _compiled_run(B: int, K: int, R: int, H: int, P: int, L: int,
             m = jnp.stack(masks).reshape(B, K, R, H, P, L)
             v = jnp.stack(valids).reshape(B, K, R, P)
             nh = jnp.stack(nhopss).reshape(B, K, R, P)
-            return call(alpha, period, aft, ct, m, v, nh,
-                        comp, ldet, flags, lf, pf, loads, lop, bp)
+            out = call(alpha, period, aft.reshape(B, 1, K), ct, m, v, nh,
+                       comp.reshape(B, 1, P), ldet.reshape(B, 1, P),
+                       flags.reshape(B, 1, 2), lf, pf, loads, lop, bp)
+            rows = tuple(x.reshape(B, *x.shape[2:]) for x in out[1:5])
+            return (out[0].reshape(B),) + rows + tuple(out[5:])
 
         run = jax.jit(run)
     _RUN_CACHE[key] = run
@@ -654,9 +669,7 @@ class PallasBackend(CandidateEvaluator):
         """Upload a float array in the kernel dtype (f64 needs the scoped
         x64 switch so jnp does not silently truncate)."""
         arr = np.asarray(arr, dtype=self._np_dtype)
-        if self._f32:
-            return jnp.asarray(arr)
-        with jax.experimental.enable_x64():
+        with _x64(self._f32):
             return jnp.asarray(arr)
 
     # ------------------------------------------------------------- state
@@ -802,13 +815,8 @@ class PallasBackend(CandidateEvaluator):
                 aft_rows.astype(dt), tuple(cts), tuple(masks),
                 tuple(valids), tuple(nhopss), comp_rows, ldet_rows,
                 flags.astype(dt), *self._state)
-        if self._f32:
+        with _x64(self._f32):
             out = run(*args)
-        else:
-            # scoped x64: without it jit canonicalizes the f64 inputs
-            # (and the kernel trace) down to f32
-            with jax.experimental.enable_x64():
-                out = run(*args)
         self.n_launches += 1
         if commit:
             # the state carry stays on device — never fetched
@@ -962,11 +970,8 @@ class PallasBackend(CandidateEvaluator):
                 exitf.astype(dt), *consts,
                 lf.astype(dt), pf.astype(dt), loads.astype(dt),
                 lop.astype(dt), bp.astype(dt), aft0.astype(dt), proc0)
-        if self._f32:
+        with _x64(self._f32):
             out = run(*args)
-        else:
-            with jax.experimental.enable_x64():
-                out = run(*args)
         self.n_launches += 1
         self.n_state_uploads += 1    # the initial-carry staging above
         fetched = jax.device_get(out)  # analysis: allow[host-sync] the documented one-per-SCHEDULE transfer (DESIGN.md §5); all decisions decode from this single fetch

@@ -359,11 +359,10 @@ class CompiledInstance:
     def sweep_supported(self, backend: Optional[str] = None) -> bool:
         """Whether :meth:`schedule_sweep` can run on this backend — i.e.
         the resolved evaluator fuses whole alpha grids into one dispatch
-        (``CandidateEvaluator.supports_plan_sweep``)."""
-        try:
-            return self.backend_instance(backend).supports_plan_sweep()
-        except Exception:
-            return False
+        (``CandidateEvaluator.supports_plan_sweep``).  A backend that
+        fails to build raises here: a broken device sweep must not pick
+        the host loop in silence."""
+        return self.backend_instance(backend).supports_plan_sweep()
 
     def schedule_sweep(self, queue: Sequence[int], alphas: Sequence[float],
                        period: Optional[float] = None,

@@ -55,12 +55,18 @@ class ScheduleValidationError(ValueError):
 
 
 def schedule_violations(s: Schedule,
-                        spec: Optional[FaultSpec] = None) -> List[str]:
-    """Every invariant violation of ``s`` (empty list == valid)."""
+                        spec: Optional[FaultSpec] = None,
+                        rtol: float = _EPS) -> List[str]:
+    """Every invariant violation of ``s`` (empty list == valid).
+
+    ``rtol`` is the comparison slack relative to the schedule horizon
+    (and to each task's computation time).  The default holds the f64
+    engines to a few ulps; a float32 device schedule is judged at its
+    documented precision (``backends.pallas.F32_NEAR_TIE_RTOL``)."""
     g, tg = s.graph, s.topology
     out: List[str] = []
     horizon = float(max(s.finish.max(), 1.0)) if g.n else 1.0
-    tol = _EPS * horizon
+    tol = rtol * horizon
     down_links = set(spec.down_links) if spec is not None else set()
     down_procs = set(spec.down_procs) if spec is not None else set()
 
@@ -77,7 +83,7 @@ def schedule_violations(s: Schedule,
             out.append(f"task {t}: malformed interval [{st}, {fi}]")
             continue
         comp = g.comp(t, p, tg.rates)
-        if abs((fi - st) - comp) > tol + _EPS * abs(comp):
+        if abs((fi - st) - comp) > tol + rtol * abs(comp):
             out.append(f"task {t}: duration {fi - st:.9g} != "
                        f"comp(t, p{p}) = {comp:.9g}")
 

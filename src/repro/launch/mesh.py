@@ -24,7 +24,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n} "
             f"(see launch/dryrun.py)")
     return jax.make_mesh(shape, axes, devices=devs[:n],
-                         **_axis_type_kwargs(len(axes)))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape, axes):
@@ -33,13 +33,4 @@ def make_mesh(shape, axes):
     n = int(np.prod(shape))
     return jax.make_mesh(tuple(shape), tuple(axes),
                          devices=jax.devices()[:n],
-                         **_axis_type_kwargs(len(axes)))
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only exists on jax >= 0.5; older versions default to
-    Auto semantics anyway."""
-    import jax.sharding as shd
-    if hasattr(shd, "AxisType"):
-        return {"axis_types": (shd.AxisType.Auto,) * n_axes}
-    return {}
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

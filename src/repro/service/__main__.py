@@ -19,6 +19,7 @@ import argparse
 import asyncio
 from typing import Optional
 
+from repro.compile_cache import use_compile_cache
 from repro.core import Topology, fully_switched_topology, paper_topology
 
 from .protocol import (ProtocolError, Response, decode_request,
@@ -133,6 +134,7 @@ def main(argv: Optional[list] = None) -> None:
     ap.add_argument("--topology", default="paper",
                     help="'paper' or 'switched:<P>'")
     args = ap.parse_args(argv)
+    use_compile_cache()
     try:
         asyncio.run(_amain(args))
     except KeyboardInterrupt:

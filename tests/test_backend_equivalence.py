@@ -292,6 +292,14 @@ PALLAS_POLICIES = [
 ]
 
 
+def assert_ran_pallas(plan):
+    """The plan came from the device backend itself, not from a demotion
+    down the fallback chain (which would hand back vector/scalar
+    decisions that trivially match)."""
+    assert plan.backend == "pallas"
+    assert plan.fallback is None
+
+
 def assert_decisions_identical(a, b):
     """Decision identity (the pallas contract): same winner tuples —
     processor assignments, message routes, replay-relevant structure —
@@ -321,7 +329,7 @@ def test_paper_example_three_way(policy):
     g, tg = paper_spg(), paper_topology()
     plans = {b: Scheduler(tg, backend=b).submit(g, policy)
              for b in ("scalar", "vector", "pallas")}
-    assert plans["pallas"].backend == "pallas"
+    assert_ran_pallas(plans["pallas"])
     for b in ("vector", "pallas"):
         pa, pb = plans["scalar"], plans[b]
         assert_decisions_identical(pa.schedule, pb.schedule)
@@ -396,6 +404,7 @@ def test_update_replay_three_way():
         task = int(np.argmax(plan.schedule.start))
         plans[backend] = sched.update(task_rates={task: 1.5})
     ua, ub = plans["scalar"], plans["pallas"]
+    assert_ran_pallas(ub)
     assert_decisions_identical(ua.schedule, ub.schedule)
     assert dataclasses.asdict(ua.replay) == dataclasses.asdict(ub.replay)
 
@@ -414,7 +423,8 @@ def test_pallas_traces_portable(record, resume):
     plan = sched.submit(g, backend=record)
     task = int(np.argmax(plan.schedule.start))
     upd = sched.update(task_rates={task: 0.8}, backend=resume)
-    assert upd.backend == resume
+    assert plan.backend == record and plan.fallback is None
+    assert upd.backend == resume and upd.fallback is None
     assert upd.replay.decisions_replayed > 0     # the resume actually ran
     fresh = Scheduler(tg, backend="scalar").submit(
         upd.graph, dataclasses.replace(policy, period=plan.period))
@@ -426,12 +436,10 @@ def test_pallas_selection_end_to_end(monkeypatch):
     default, per-call override, env var — and auto never picks it."""
     pytest.importorskip("jax")
     g, tg = paper_spg(), paper_topology()
-    assert Scheduler(tg, backend="pallas").submit(
-        g, ONE_POINT).backend == "pallas"
-    assert Scheduler(tg).submit(
-        g, ONE_POINT, backend="pallas").backend == "pallas"
+    assert_ran_pallas(Scheduler(tg, backend="pallas").submit(g, ONE_POINT))
+    assert_ran_pallas(Scheduler(tg).submit(g, ONE_POINT, backend="pallas"))
     monkeypatch.setenv("REPRO_SCHED_BACKEND", "pallas")
-    assert Scheduler(tg).submit(g, ONE_POINT).backend == "pallas"
+    assert_ran_pallas(Scheduler(tg).submit(g, ONE_POINT))
     monkeypatch.delenv("REPRO_SCHED_BACKEND")
     g8, tg8 = _wide(AUTO_VECTOR_MIN_P, 5)
     assert Scheduler(tg8).submit(g8, ONE_POINT).backend == "vector"

@@ -148,3 +148,28 @@ def test_fallback_warns_only_once(monkeypatch):
         _w.simplefilter("error")            # a second warn would raise
         plan = sched.submit(g2)
     assert plan.fallback is not None        # still recorded on the plan
+
+
+def test_broken_device_sweep_is_not_silently_hosted(monkeypatch):
+    """A device backend that fails to build raises from
+    ``sweep_supported`` instead of quietly picking the host per-alpha
+    loop; at the session level that failure takes the visible fallback
+    chain (recorded on ``Plan.fallback``, warned)."""
+    pytest.importorskip("jax")
+    from repro.core import CompiledInstance
+    from repro.core.backends.pallas import PallasBackend
+
+    def _boom(self, inst):
+        raise RuntimeError("injected backend build failure")
+
+    monkeypatch.setattr(PallasBackend, "__init__", _boom)
+    monkeypatch.setattr(api_mod, "_FALLBACK_WARNED", set())
+    tg, g = _case()
+    with pytest.raises(RuntimeError, match="injected backend build"):
+        CompiledInstance(g, tg).sweep_supported("pallas")
+    assert CompiledInstance(g, tg).sweep_supported("scalar") is False
+    sched = Scheduler(tg, policy=_pol(), backend="pallas")
+    with pytest.warns(RuntimeWarning, match="injected backend build"):
+        plan = sched.submit(g)
+    assert plan.fallback is not None and plan.fallback[0][0] == "pallas"
+    _assert_same_decisions(plan, _scalar_reference(tg, g))
