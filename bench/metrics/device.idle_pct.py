@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran on the device."""
+
+
+def read(m):
+    if m.summary is None:
+        return None
+    return m.summary.idle_pct
