@@ -59,6 +59,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from .. import tracing
 from .backends import (PALLAS, default_backend, resolve_backend_name,
                        vector_compatible)
 from .deprecation import warn_once
@@ -344,22 +345,24 @@ class _GraphSession:
     @property
     def inst(self) -> Optional[CompiledInstance]:
         if self._compiled and self._inst is None:
-            self._inst = CompiledInstance(self.g, self._tg, rank=self.rank,
-                                          ldet=self.ldet,
-                                          faults=self._faults)
+            with tracing.span("repro.api.prepare"):
+                self._inst = CompiledInstance(
+                    self.g, self._tg, rank=self.rank, ldet=self.ldet,
+                    faults=self._faults)
         return self._inst
 
     def queue_for(self, tg: Topology, policy: Policy) -> List[int]:
         key = _queue_key(policy)
         q = self.queues.get(key)
         if q is None:
-            g, rank = self.g, self.rank
-            if key[0] == "b":
-                prv = hprv_b(g, tg, rank, depth_power=policy.depth_power,
-                             outd_mode=policy.outd_mode)
-            else:
-                prv = hprv_a(g, tg, rank)
-            q = priority_queue(prv, rank.mean(axis=1))
+            with tracing.span("repro.api.prepare"):
+                g, rank = self.g, self.rank
+                if key[0] == "b":
+                    prv = hprv_b(g, tg, rank, depth_power=policy.depth_power,
+                                 outd_mode=policy.outd_mode)
+                else:
+                    prv = hprv_a(g, tg, rank)
+                q = priority_queue(prv, rank.mean(axis=1))
             self.queues[key] = q
         return q
 
@@ -560,6 +563,7 @@ class Scheduler:
         return chain
 
     # ------------------------------------------------------------- submit
+    @tracing.traced("repro.api.submit")
     def submit(self, g: SPG, policy: Optional[Policy] = None,
                backend: Optional[str] = None,
                batch: Optional[int] = None) -> Plan:
@@ -574,10 +578,11 @@ class Scheduler:
         bcap = self._resolve_batch(batch)
         sess = self._sessions.get(id(g))
         if sess is None or sess.g is not g:
-            check_graph(g)       # actionable errors at the boundary
-            sess = _GraphSession(g, self.topology,
-                                 compiled=self.engine == "compiled",
-                                 faults=self._spec)
+            with tracing.span("repro.api.prepare"):
+                check_graph(g)       # actionable errors at the boundary
+                sess = _GraphSession(g, self.topology,
+                                     compiled=self.engine == "compiled",
+                                     faults=self._spec)
             self._sessions[id(g)] = sess
         self._last = sess
         plan = sess.plans.get((policy, bname, bcap))
@@ -587,6 +592,7 @@ class Scheduler:
             sess.plans[(policy, bname, bcap)] = plan
         return plan
 
+    @tracing.traced("repro.api.submit_many")
     def submit_many(self, graphs: Iterable[SPG],
                     policy: Optional[Policy] = None,
                     backend: Optional[str] = None,
@@ -615,6 +621,7 @@ class Scheduler:
                          fallback=plan.fallback)
 
     # ------------------------------------------------------------- update
+    @tracing.traced("repro.api.probe_update")
     def probe_update(self, *, task_rates: Dict[int, float],
                      graph: Optional[SPG] = None,
                      policy: Optional[Policy] = None) -> int:
@@ -638,14 +645,16 @@ class Scheduler:
             return queue_len
         if self.engine != "compiled":
             return 0
-        new_sess = _GraphSession(_rescaled_graph(sess.g, [changed]),
-                                 self.topology, compiled=True,
-                                 faults=self._spec)
+        with tracing.span("repro.api.prepare"):
+            new_sess = _GraphSession(_rescaled_graph(sess.g, [changed]),
+                                     self.topology, compiled=True,
+                                     faults=self._spec)
         prefix = self._clean_prefix(sess, new_sess, policy)
         self._probe = (sess, policy, tuple(sorted(changed.items())),
                        new_sess, prefix)
         return prefix
 
+    @tracing.traced("repro.api.update")
     def update(self, *,
                task_rates: Union[Dict[int, float],
                                  Sequence[Dict[int, float]], None] = None,
@@ -718,9 +727,10 @@ class Scheduler:
         else:
             new_g = _rescaled_graph(sess.g, changed_events) \
                 if changed_events else sess.g
-            new_sess = _GraphSession(new_g, self.topology,
-                                     compiled=self.engine == "compiled",
-                                     faults=self._spec)
+            with tracing.span("repro.api.prepare"):
+                new_sess = _GraphSession(new_g, self.topology,
+                                         compiled=self.engine == "compiled",
+                                         faults=self._spec)
             suffix_start = 0
             if self.engine == "compiled" and not link_changed:
                 suffix_start = self._clean_prefix(sess, new_sess, policy)
@@ -754,6 +764,7 @@ class Scheduler:
         """The active resource-fault spec (empty when healthy)."""
         return self._spec
 
+    @tracing.traced("repro.api.mark_failed")
     def mark_failed(self, *, proc: Optional[int] = None,
                     link: Optional[str] = None,
                     graph: Optional[SPG] = None,
@@ -784,6 +795,7 @@ class Scheduler:
             else LinkDown(link)
         return self._apply_fault(fault, graph, policy, backend, batch)
 
+    @tracing.traced("repro.api.degrade")
     def degrade(self, *, link: Optional[str] = None,
                 task: Optional[int] = None, factor: float,
                 graph: Optional[SPG] = None,
@@ -815,6 +827,7 @@ class Scheduler:
         return self._apply_fault(LinkDegraded(link, float(factor)),
                                  graph, policy, backend, batch)
 
+    @tracing.traced("repro.api.restore")
     def restore(self, *, proc: Optional[int] = None,
                 link: Optional[str] = None,
                 graph: Optional[SPG] = None,
@@ -871,10 +884,11 @@ class Scheduler:
                 suffix_start = min(
                     self._fault_prefix(tr, scan) for tr in traces.values())
         prev_traces = sess.traces.get(policy) if suffix_start > 0 else None
-        new_sess = _GraphSession(sess.g, self.topology,
-                                 compiled=self.engine == "compiled",
-                                 faults=new_spec,
-                                 rank=sess.rank, ldet=sess.ldet)
+        with tracing.span("repro.api.prepare"):
+            new_sess = _GraphSession(sess.g, self.topology,
+                                     compiled=self.engine == "compiled",
+                                     faults=new_spec,
+                                     rank=sess.rank, ldet=sess.ldet)
         new_sess.queues = dict(sess.queues)      # healthy heuristics
         new_sess.periods = dict(sess.periods)    # keep the pinned period
         bname, pending = self._resolve_backend_fb(backend)

@@ -98,6 +98,9 @@ launches, blocking device->host transfers, and host->device state
 re-uploads; ``benchmarks/exp7`` records launches per schedule and the
 CI gate holds the per-schedule total at a constant (<= 3: upload,
 dispatch, fetch) on the scan path and O(levels) on the per-wave path.
+With :mod:`repro.tracing` on, the scan path's set-up, table upload,
+staging, launch, fetch and decode are spans and the ``backend.*``
+counters count launches and bytes each way (DESIGN.md §10).
 """
 from __future__ import annotations
 
@@ -113,6 +116,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from ... import tracing
 from .base import CandidateEvaluator, Decision
 from ..faults import WaveTimeoutError
 from .layout import (LANE, SUBLANE_F32, pad_dim, padded_edge_ct,
@@ -441,9 +445,11 @@ def _scan_run(W: int, B: int, K: int, R: int, H: int, Pp: int, Lp: int,
     f = jnp.float32 if f32 else jnp.float64
     i32 = jnp.int32
 
-    def schedule(alpha, period, task, real, pred, pvalid, edge, exitf,
-                 masks_all, valid_all, nhops_all, ct_all, comp_all,
-                 ldet_all, lf0, pf0, loads0, lop0, bp0, aft0, proc0):
+    # the jitted functions' names name the XLA module on the device
+    # trace (``jit_repro_scan`` / ``jit_repro_scan_sweep``)
+    def repro_scan(alpha, period, task, real, pred, pvalid, edge, exitf,
+                   masks_all, valid_all, nhops_all, ct_all, comp_all,
+                   ldet_all, lf0, pf0, loads0, lop0, bp0, aft0, proc0):
         one = jnp.array(1.0, dtype=f)
         neg = jnp.array(_NEG_INF, dtype=f)
         pad_src = jnp.int32(masks_all.shape[0] - 1)
@@ -586,23 +592,27 @@ def _scan_run(W: int, B: int, K: int, R: int, H: int, Pp: int, Lp: int,
 
         carry0 = (lf0, pf0, loads0, lop0, bp0, aft0, proc0)
         xs = (task, real, pred, pvalid, edge, exitf)
-        _, ys = jax.lax.scan(wave_step, carry0, xs)
+        # the body is traced inside the scope: it names the wave's
+        # operations in the op metadata
+        with jax.named_scope("repro.scan.wave"):
+            _, ys = jax.lax.scan(wave_step, carry0, xs)
         return ys
 
     if A:
-        def run(alphas, period, task, real, pred, pvalid, edge, exitf,
-                masks_all, valid_all, nhops_all, ct_all, comp_all,
-                ldet_all, lf0, pf0, loads0, lop0, bp0, aft0, proc0):
+        def repro_scan_sweep(alphas, period, task, real, pred, pvalid,
+                             edge, exitf, masks_all, valid_all, nhops_all,
+                             ct_all, comp_all, ldet_all, lf0, pf0, loads0,
+                             lop0, bp0, aft0, proc0):
             def one(al):
-                return schedule(al, period, task, real, pred, pvalid,
-                                edge, exitf, masks_all, valid_all,
-                                nhops_all, ct_all, comp_all, ldet_all,
-                                lf0, pf0, loads0, lop0, bp0, aft0, proc0)
+                return repro_scan(al, period, task, real, pred, pvalid,
+                                  edge, exitf, masks_all, valid_all,
+                                  nhops_all, ct_all, comp_all, ldet_all,
+                                  lf0, pf0, loads0, lop0, bp0, aft0, proc0)
             return jax.vmap(one)(alphas)
 
-        run = jax.jit(run)
+        run = jax.jit(repro_scan_sweep)
     else:
-        run = jax.jit(schedule)
+        run = jax.jit(repro_scan)
     _RUN_CACHE[key] = run
     while len(_RUN_CACHE) > _RUN_CACHE_MAX:
         _RUN_CACHE.popitem(last=False)
@@ -614,6 +624,7 @@ class PallasBackend(CandidateEvaluator):
 
     name = "pallas"
 
+    @tracing.traced("repro.backend.build")
     def __init__(self, inst) -> None:
         super().__init__(inst)
         self._interpret = _use_interpret()
@@ -643,6 +654,9 @@ class PallasBackend(CandidateEvaluator):
                      self._to_dev(np.zeros((R, H, Pp, Lp))),
                      self._to_dev(pad_valid),
                      self._to_dev(np.zeros((R, Pp))))
+        if tracing.enabled():
+            tracing.count("backend.h2d_bytes",
+                          sum(a.nbytes for a in self._pad))
         # comp rows padded with +inf lanes (padded lanes never win);
         # ldet rows: exit tasks and padded lanes read exactly 1.0
         comp_pad = np.full((inst.n, Pp), _INF)
@@ -872,19 +886,23 @@ class PallasBackend(CandidateEvaluator):
         scale).  Task-indexed comp/ldet rows are padded to the bucketed
         ``Np`` (pad rows are never gathered — task ids are < n)."""
         if self._scan_dev is None:
-            inst = self.inst
-            n, Np = inst.n, self._Np
-            masks, valid, nhops = stacked_src_tensors(
-                inst, self._R, self._H, self._Pp, self._Lp)
-            ct = stacked_edge_ct(inst, self._R, self._H, self._Pp,
-                                 self._Ep)
-            comp = np.zeros((Np, self._Pp))
-            comp[:n] = self._comp_rows
-            ldet = np.ones((Np, self._Pp))
-            ldet[:n] = self._ldet_rows
-            self._scan_dev = tuple(
-                self._to_dev(x)
-                for x in (masks, valid, nhops, ct, comp, ldet))
+            with tracing.span("repro.backend.tables"):
+                inst = self.inst
+                n, Np = inst.n, self._Np
+                masks, valid, nhops = stacked_src_tensors(
+                    inst, self._R, self._H, self._Pp, self._Lp)
+                ct = stacked_edge_ct(inst, self._R, self._H, self._Pp,
+                                     self._Ep)
+                comp = np.zeros((Np, self._Pp))
+                comp[:n] = self._comp_rows
+                ldet = np.ones((Np, self._Pp))
+                ldet[:n] = self._ldet_rows
+                self._scan_dev = tuple(
+                    self._to_dev(x)
+                    for x in (masks, valid, nhops, ct, comp, ldet))
+                if tracing.enabled():
+                    tracing.count("backend.h2d_bytes",
+                                  sum(a.nbytes for a in self._scan_dev))
         return self._scan_dev
 
     def _scan_inputs(self, waves: Sequence[Sequence[int]]) -> tuple:
@@ -934,48 +952,60 @@ class PallasBackend(CandidateEvaluator):
         inst = self.inst
         P, Pp, L, Lp = inst.P, self._Pp, self._L, self._Lp
         n, Np = inst.n, self._Np
-        Wp, Bp, task, real, pred, pvalid, edge, exitf = \
-            self._scan_inputs(waves)
         consts = self._scan_tables()
-        dt = self._np_dtype
-        lf = np.zeros(Lp)
-        lf[:L] = self.link_free
-        pf = np.zeros(Pp)
-        pf[:P] = self.proc_free
-        loads = np.zeros(Pp)
-        loads[:P] = self.loads
-        lop = np.zeros(Pp)
-        lop[:P] = self._lop
-        bp = np.ones(Pp)
-        bp[:P] = self._bp
-        aft0 = np.zeros(Np)
-        aft0[:n] = self.aft
-        # unscheduled tasks point at the pad source plane P (only ever
-        # gathered through a scheduled predecessor, but a negative index
-        # would wrap)
-        proc0 = np.full(Np, P, np.int32)
-        proc0[:n] = [p if p >= 0 else P for p in self.proc_of]
-        if alphas is None:
-            Ap = 0
-            a_arg = np.asarray(self.alpha, dtype=dt)
-        else:
-            Ap = _bucket(len(alphas))
-            a_arg = np.asarray(
-                list(alphas) + [alphas[-1]] * (Ap - len(alphas)),
-                dtype=dt)
-        run = _scan_run(Wp, Bp, self._K, self._R, self._H, Pp, Lp, Np,
-                        self._Ep, Ap, self._f32)
-        args = (a_arg, np.asarray(self.period, dtype=dt),
-                task, real.astype(dt), pred, pvalid.astype(dt), edge,
-                exitf.astype(dt), *consts,
-                lf.astype(dt), pf.astype(dt), loads.astype(dt),
-                lop.astype(dt), bp.astype(dt), aft0.astype(dt), proc0)
-        with _x64(self._f32):
-            out = run(*args)
+        with tracing.span("repro.backend.stage"):
+            Wp, Bp, task, real, pred, pvalid, edge, exitf = \
+                self._scan_inputs(waves)
+            dt = self._np_dtype
+            lf = np.zeros(Lp)
+            lf[:L] = self.link_free
+            pf = np.zeros(Pp)
+            pf[:P] = self.proc_free
+            loads = np.zeros(Pp)
+            loads[:P] = self.loads
+            lop = np.zeros(Pp)
+            lop[:P] = self._lop
+            bp = np.ones(Pp)
+            bp[:P] = self._bp
+            aft0 = np.zeros(Np)
+            aft0[:n] = self.aft
+            # unscheduled tasks point at the pad source plane P (only
+            # ever gathered through a scheduled predecessor, but a
+            # negative index would wrap)
+            proc0 = np.full(Np, P, np.int32)
+            proc0[:n] = [p if p >= 0 else P for p in self.proc_of]
+            if alphas is None:
+                Ap = 0
+                a_arg = np.asarray(self.alpha, dtype=dt)
+            else:
+                Ap = _bucket(len(alphas))
+                a_arg = np.asarray(
+                    list(alphas) + [alphas[-1]] * (Ap - len(alphas)),
+                    dtype=dt)
+            run = _scan_run(Wp, Bp, self._K, self._R, self._H, Pp, Lp, Np,
+                            self._Ep, Ap, self._f32)
+            args = (a_arg, np.asarray(self.period, dtype=dt),
+                    task, real.astype(dt), pred, pvalid.astype(dt), edge,
+                    exitf.astype(dt), *consts,
+                    lf.astype(dt), pf.astype(dt), loads.astype(dt),
+                    lop.astype(dt), bp.astype(dt), aft0.astype(dt), proc0)
+        with tracing.span("repro.backend.launch"):
+            with _x64(self._f32):
+                out = run(*args)
         self.n_launches += 1
         self.n_state_uploads += 1    # the initial-carry staging above
-        fetched = jax.device_get(out)  # analysis: allow[host-sync] the documented one-per-SCHEDULE transfer (DESIGN.md §5); all decisions decode from this single fetch
+        with tracing.span("repro.backend.fetch"):
+            fetched = jax.device_get(out)  # analysis: allow[host-sync] the documented one-per-SCHEDULE transfer (DESIGN.md §5); all decisions decode from this single fetch
         self.n_roundtrips += 1
+        if tracing.enabled():
+            tracing.count("backend.launches")
+            # the host arrays staged above (the tables are already on
+            # the device, counted when they were uploaded)
+            tracing.count("backend.h2d_bytes",
+                          sum(a.nbytes for a in args
+                              if isinstance(a, np.ndarray)))
+            tracing.count("backend.d2h_bytes",
+                          sum(a.nbytes for a in fetched))
         return tuple(fetched)
 
     def _decode_scan(self, waves: Sequence[Sequence[int]], outs: tuple,
@@ -1057,8 +1087,9 @@ class PallasBackend(CandidateEvaluator):
         # the per-wave device carry is now stale relative to the
         # mirrors; any later per-wave launch re-uploads first
         self._state_dirty = True
-        return self._decode_scan(waves, outs, self.alpha, True,
-                                 self.want_bound)
+        with tracing.span("repro.backend.decode"):
+            return self._decode_scan(waves, outs, self.alpha, True,
+                                     self.want_bound)
 
     def supports_plan_sweep(self) -> bool:
         return _use_scan()
@@ -1083,6 +1114,7 @@ class PallasBackend(CandidateEvaluator):
             budget = timeout * len(waves) * len(alphas)
             if elapsed > budget:
                 raise WaveTimeoutError(0, elapsed, budget)
-        return [self._decode_scan(waves, tuple(o[ai] for o in outs),
-                                  alpha, False, True)
-                for ai, alpha in enumerate(alphas)]
+        with tracing.span("repro.backend.decode"):
+            return [self._decode_scan(waves, tuple(o[ai] for o in outs),
+                                      alpha, False, True)
+                    for ai, alpha in enumerate(alphas)]

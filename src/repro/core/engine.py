@@ -75,6 +75,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import tracing
 from .backends import CandidateEvaluator, backend_class, resolve_backend_name
 from .faults import (DOWN_COMP, INFEASIBLE_EFT, FaultSpec,
                      InfeasibleScheduleError)
@@ -405,36 +406,41 @@ class CompiledInstance:
         faulted = self.faults is not None
         swept = be.evaluate_plan_sweep(waves, list(alphas), period,
                                        timeout=self.wave_timeout)
-        out: List[Tuple[Schedule, float, DecisionTrace]] = []
-        for alpha, per_wave in zip(alphas, swept):
-            messages: Dict[Tuple[int, int], MessagePlacement] = {}
-            records: List[DecisionRecord] = []
-            bound = _INF
-            procs = np.full(self.n, -1, dtype=np.int64)
-            ast_ = np.zeros(self.n)
-            aft_ = np.zeros(self.n)
-            bid = 0
-            for wave_js, decisions in zip(waves, per_wave):
-                for j, (p, est, eft, msgs, ca, cb, contrib) in zip(
-                        wave_js, decisions):
-                    if faulted and not eft < INFEASIBLE_EFT:
-                        raise InfeasibleScheduleError(j, eft, self.faults)
-                    for (i, route, iv) in msgs:
-                        messages[(i, j)] = MessagePlacement(
-                            (i, j), int(procs[i]), p, route,
-                            [(names[lid], s_, f) for (lid, s_, f) in iv])
-                    procs[j] = p
-                    ast_[j] = est
-                    aft_[j] = eft
-                    if contrib < bound:
-                        bound = contrib
-                    records.append((j, p, est, eft, msgs, ca, cb, bid))
-                bid += 1
-            self.n_decisions_simulated += len(records)
-            tr = DecisionTrace(tuple(queue), alpha, period, True, records)
-            out.append((Schedule(g, tg, procs, ast_, aft_, messages,
-                                 alpha=alpha), bound, tr))
-        return out
+        # decisions to plan objects: one span over every alpha
+        with tracing.span("repro.engine.assemble"):
+            out: List[Tuple[Schedule, float, DecisionTrace]] = []
+            for alpha, per_wave in zip(alphas, swept):
+                messages: Dict[Tuple[int, int], MessagePlacement] = {}
+                records: List[DecisionRecord] = []
+                bound = _INF
+                procs = np.full(self.n, -1, dtype=np.int64)
+                ast_ = np.zeros(self.n)
+                aft_ = np.zeros(self.n)
+                bid = 0
+                for wave_js, decisions in zip(waves, per_wave):
+                    for j, (p, est, eft, msgs, ca, cb, contrib) in zip(
+                            wave_js, decisions):
+                        if faulted and not eft < INFEASIBLE_EFT:
+                            raise InfeasibleScheduleError(j, eft,
+                                                          self.faults)
+                        for (i, route, iv) in msgs:
+                            messages[(i, j)] = MessagePlacement(
+                                (i, j), int(procs[i]), p, route,
+                                [(names[lid], s_, f)
+                                 for (lid, s_, f) in iv])
+                        procs[j] = p
+                        ast_[j] = est
+                        aft_[j] = eft
+                        if contrib < bound:
+                            bound = contrib
+                        records.append((j, p, est, eft, msgs, ca, cb, bid))
+                    bid += 1
+                self.n_decisions_simulated += len(records)
+                tr = DecisionTrace(tuple(queue), alpha, period, True,
+                                   records)
+                out.append((Schedule(g, tg, procs, ast_, aft_, messages,
+                                     alpha=alpha), bound, tr))
+            return out
 
     # ------------------------------------------------------------------
     def _run(self, queue: Sequence[int], alpha: float,
@@ -523,26 +529,28 @@ class CompiledInstance:
         faulted = self.faults is not None
         per_wave = be.evaluate_plan(waves, timeout=self.wave_timeout,
                                     bid0=bid)
-        for wave_js, decisions in zip(waves, per_wave):
-            for j, (p, est, eft, msgs, ca, cb, contrib) in zip(wave_js,
-                                                               decisions):
-                if faulted and not eft < INFEASIBLE_EFT:
-                    # the *winner* is only reachable through a masked
-                    # resource: no feasible placement exists for j
-                    raise InfeasibleScheduleError(j, eft, self.faults)
-                for (i, route, iv) in msgs:
-                    messages[(i, j)] = MessagePlacement(
-                        (i, j), proc_of[i], p, route,
-                        [(names[lid], s_, f) for (lid, s_, f) in iv])
-                if contrib < bound:
-                    bound = contrib
-                if record:
-                    records.append((j, p, est, eft, msgs, ca, cb, bid))
-            sim_count += len(wave_js)
-            bid += 1
+        with tracing.span("repro.engine.assemble"):
+            for wave_js, decisions in zip(waves, per_wave):
+                for j, (p, est, eft, msgs, ca, cb, contrib) in zip(
+                        wave_js, decisions):
+                    if faulted and not eft < INFEASIBLE_EFT:
+                        # the *winner* is only reachable through a masked
+                        # resource: no feasible placement exists for j
+                        raise InfeasibleScheduleError(j, eft, self.faults)
+                    for (i, route, iv) in msgs:
+                        messages[(i, j)] = MessagePlacement(
+                            (i, j), proc_of[i], p, route,
+                            [(names[lid], s_, f) for (lid, s_, f) in iv])
+                    if contrib < bound:
+                        bound = contrib
+                    if record:
+                        records.append((j, p, est, eft, msgs, ca, cb, bid))
+                sim_count += len(wave_js)
+                bid += 1
 
-        self.n_decisions_simulated += sim_count
-        trace = DecisionTrace(tuple(queue), alpha,
-                              period, want_bound, records) if record else None
-        return Schedule(g, tg, np.array(proc_of), np.array(be.ast),
-                        np.array(be.aft), messages, alpha=alpha), bound, trace
+            self.n_decisions_simulated += sim_count
+            trace = DecisionTrace(tuple(queue), alpha, period, want_bound,
+                                  records) if record else None
+            return Schedule(g, tg, np.array(proc_of), np.array(be.ast),
+                            np.array(be.aft), messages,
+                            alpha=alpha), bound, trace

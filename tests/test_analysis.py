@@ -1090,3 +1090,27 @@ def test_shipped_repo_analyzes_clean():
     code, out, _ = run_cli([])
     assert code == 0, f"shipped tree has analyzer findings:\n{out}"
     assert "clean" in out
+
+
+def test_shipped_scan_carry_is_checked():
+    """The scan-carry rules reach the shipped whole-schedule scan: the
+    analyzer resolves its body, reads all seven carried leaves and finds
+    them sound.  A wrapper around the body would hide the carry and turn
+    both rules off without a finding."""
+    import ast
+    from pathlib import Path
+
+    import repro.analysis
+    from repro.analysis import kernels
+    from repro.analysis.index import ProjectIndex
+
+    path = (Path(repro.analysis.__file__).parents[1] / "core" / "backends"
+            / "pallas.py")
+    sf = ProjectIndex().load(path, "pallas.py")
+    calls = [node for node in ast.walk(sf.functions["_scan_run"])
+             if isinstance(node, ast.Call) and kernels._is_scan_call(node)]
+    assert len(calls) == 1
+    body = sf.functions[calls[0].args[0].id]
+    assert kernels._carry_leaves(body) == [
+        "lf", "pf", "loads", "lop", "bp", "aft_t", "proc_t"]
+    assert kernels._check_scan("pallas.py", calls[0], sf.functions) == []
