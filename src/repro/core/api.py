@@ -1126,23 +1126,24 @@ class Scheduler:
             # resumable update goes through the host loop below, which
             # replays per-alpha trace prefixes.  Selection matches the
             # host loop exactly: trace-invariance means the alphas the
-            # host loop would have skipped produce bit-equal schedules
+            # host loop would have skipped produce bit-equal makespans
             # here, and the same strict-improvement rule scans them in
-            # the same order.
+            # the same order.  Only alpha*'s schedule is built; every
+            # other alpha's trace builds when an update or a fault
+            # replan first reads it.
             alphas = [k * policy.alpha_step for k in range(n_steps + 1)]
             swept = inst.schedule_sweep(queue, alphas, period=period,
                                         backend=backend, batch=batch)
-            fbest: Optional[Schedule] = None
-            fbest_alpha = 0.0
-            fpoints: List[Tuple[float, float]] = []
-            for alpha, (s, _bnd, tr) in zip(alphas, swept):
-                traces[alpha] = tr
-                fpoints.append((alpha, s.makespan))
+            makespans = swept.makespans.tolist()
+            k_best = 0
+            for k in range(1, len(alphas)):
                 # analysis: allow[float-arith] strict-improvement epsilon on a reduction over backend outputs, not a per-decision value
-                if fbest is None or s.makespan < fbest.makespan - 1e-12:
-                    fbest, fbest_alpha = s, alpha
-            assert fbest is not None
-            return (SweepResult.from_points(fbest, fbest_alpha, fpoints),
+                if makespans[k] < makespans[k_best] - 1e-12:
+                    k_best = k
+            for k, alpha in enumerate(alphas):
+                traces[alpha] = swept.trace(k)
+            return (SweepResult.from_points(swept[k_best][0], alphas[k_best],
+                                            list(zip(alphas, makespans))),
                     0, len(alphas))
 
         def grid_pass(alphas: Sequence[float], points, best, best_alpha):

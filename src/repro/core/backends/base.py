@@ -29,14 +29,18 @@ from __future__ import annotations
 
 import abc
 import time
-from typing import ClassVar, List, Optional, Sequence, Tuple, TYPE_CHECKING
+from typing import (Callable, ClassVar, List, NamedTuple, Optional, Sequence,
+                    Tuple, TYPE_CHECKING)
+
+import numpy as np
 
 from ..faults import WaveTimeoutError
 
 if TYPE_CHECKING:                                   # pragma: no cover
     from ..engine import CompiledInstance
 
-__all__ = ["BackendCompatError", "CandidateEvaluator", "Decision"]
+__all__ = ["BackendCompatError", "CandidateEvaluator", "Decision",
+           "PlanSweep"]
 
 _INF = float("inf")
 
@@ -57,6 +61,21 @@ class BackendCompatError(ValueError):
 # with ``msgs`` = [(pred, route, [(link_id, lst, lft), ...]), ...].
 Decision = Tuple[int, float, float, list, Optional[tuple], Optional[tuple],
                  float]
+
+
+class PlanSweep(NamedTuple):
+    """What :meth:`CandidateEvaluator.evaluate_plan_sweep` returns.
+
+    ``eft[a, k]`` is the winner's EFT of the ``k``-th decision of the wave
+    plan (the waves flattened, i.e. queue order) under the ``a``-th alpha,
+    in the backend's dtype: enough to price and select every alpha at
+    once.  ``decode(a)`` gives alpha ``a``'s ``[wave] -> decisions``,
+    built only when asked for; it commits nothing, so it may run any time
+    later, from any thread, and gives the same decisions each time.
+    """
+
+    eft: np.ndarray
+    decode: Callable[[int], List[List[Decision]]]
 
 
 class CandidateEvaluator(abc.ABC):
@@ -172,19 +191,20 @@ class CandidateEvaluator(abc.ABC):
 
     def evaluate_plan_sweep(self, waves: Sequence[Sequence[int]],
                             alphas: Sequence[float], period: float,
-                            timeout: Optional[float] = None
-                            ) -> List[List[List[Decision]]]:
+                            timeout: Optional[float] = None) -> PlanSweep:
         """Evaluate one wave plan under *every* alpha of a sweep grid in
         a single dispatch (the (A, B) fused launch, DESIGN.md §5).
 
-        Returns ``[alpha][wave] -> decisions`` with per-alpha decisions
-        identical to ``len(alphas)`` independent :meth:`evaluate_plan`
-        runs.  Decodes with bound tracking (``cand_A``/``cand_B``
-        populated) so the recorded traces resume exactly like host-loop
-        sweep traces.  Must NOT commit to the backend's run state — the
-        per-alpha runs are independent; callers re-``start()`` before
-        reusing the instance.  Only called when
-        :meth:`supports_plan_sweep` is true.
+        Returns a :class:`PlanSweep`: every alpha's winner EFTs at once,
+        and each alpha's decisions on request, identical to
+        ``len(alphas)`` independent :meth:`evaluate_plan` runs and
+        decoded with bound tracking (``cand_A``/``cand_B`` populated) so
+        the recorded traces resume exactly like host-loop sweep traces.
+        A caller that reads one alpha pays the decode of one.  Must NOT
+        commit to the backend's run state — the per-alpha runs are
+        independent, and an alpha decoded later must not read run state
+        another plan has committed since; callers re-``start()`` before
+        reusing the instance.  Only called when :meth:`supports_plan_sweep` is true.
         """
         raise NotImplementedError(
             f"backend {self.name!r} does not fuse alpha sweeps")

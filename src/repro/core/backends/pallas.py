@@ -89,7 +89,9 @@ source rows dynamically.  One upload, one launch, one blocking fetch
 per schedule: host round-trips drop O(levels) -> O(1).  The HVLB_CC
 alpha sweep folds in as one more batch axis (``evaluate_plan_sweep``):
 a ``vmap`` over the alpha grid evaluates every alpha's schedule in the
-same dispatch.  ``REPRO_PALLAS_SCAN=0`` falls back to the per-wave
+same dispatch; the host keeps each decision's winner row, reads every
+alpha's EFTs from it, and decodes an alpha's decisions only when they
+are read.  ``REPRO_PALLAS_SCAN=0`` falls back to the per-wave
 kernel loop (which also serves single-decision ``evaluate`` protocol
 calls and remains the numerics reference for the scan).
 
@@ -117,7 +119,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from ... import tracing
-from .base import CandidateEvaluator, Decision
+from .base import CandidateEvaluator, Decision, PlanSweep
 from ..faults import WaveTimeoutError
 from .layout import (LANE, SUBLANE_F32, pad_dim, padded_edge_ct,
                      padded_src_tensors, src_layout, stacked_edge_ct,
@@ -619,6 +621,25 @@ def _scan_run(W: int, B: int, K: int, R: int, H: int, Pp: int, Lp: int,
     return run
 
 
+def _gather_winners(outs: tuple, waves: Sequence[Sequence[int]], A: int,
+                    P: int) -> tuple:
+    """Each real decision's row of fetched scan outputs at its winning
+    processor, in queue order (the waves flattened), for the first ``A``
+    entries of the leading alpha axis: ``win``, ``est``, ``eft`` (A, q);
+    ``ca``, ``cb`` over the real processors (A, q, P); ``lst``, ``lft``
+    (A, q, K, H); ``bestr`` (A, q, K).  Padded alphas, waves, slots and
+    processor lanes are dropped, and advanced indexing copies, so the
+    padded fetch can be freed."""
+    win, est, eft, ca, cb, lst, lft, bestr = outs
+    wv = np.repeat(np.arange(len(waves)), [len(w) for w in waves])
+    b = np.concatenate([np.arange(len(w)) for w in waves])
+    a = np.arange(A)[:, None]
+    p = win[:A, wv, b]
+    return (p, est[a, wv, b, p], eft[a, wv, b, p], ca[:A, wv, b, :P],
+            cb[:A, wv, b, :P], lst[a, wv, b, :, :, p],
+            lft[a, wv, b, :, :, p], bestr[a, wv, b, :, p])
+
+
 class PallasBackend(CandidateEvaluator):
     """Device-batched candidate evaluation: one Pallas kernel per wave."""
 
@@ -1011,24 +1032,28 @@ class PallasBackend(CandidateEvaluator):
     def _decode_scan(self, waves: Sequence[Sequence[int]], outs: tuple,
                      alpha: float, commit: bool,
                      want_bound: bool) -> List[List[Decision]]:
-        """Decode one schedule's fetched scan outputs into per-wave
-        decision lists.  The host re-derives each decision's sorted
-        predecessor order from the (already decoded) committed AFT
-        mirrors — f64 -> kernel-dtype casting is monotone, so it matches
-        the device's ``(aft, id)`` sort on the f64 path exactly (and
-        within the near-tie policy on f32)."""
+        """Decode one schedule's winner rows (:func:`_gather_winners`,
+        one alpha) into per-wave decision lists.  The host re-derives
+        each decision's sorted predecessor order from the (already
+        decoded) committed AFT mirrors — f64 -> kernel-dtype casting is
+        monotone, so it matches the device's ``(aft, id)`` sort on the
+        f64 path exactly (and within the near-tie policy on f32)."""
         inst = self.inst
-        P = inst.P
-        win, est, eft, ca_all, cb_all, lst, lft, bestr = outs
+        win, est, eft, ca_all, cb_all, lst, lft, bestr = (
+            x.tolist() for x in outs)
         if commit:
             aft_l, proc_l = self.aft, self.proc_of
         else:
-            aft_l, proc_l = list(self.aft), list(self.proc_of)
+            # one alpha of a sweep, decoded whenever it is read, so it
+            # reads no run state: the sweep's queue holds every
+            # predecessor, whose entry is set below before it is read
+            aft_l, proc_l = [0.0] * inst.n, [-1] * inst.n
         out: List[List[Decision]] = []
-        for wv, js in enumerate(waves):
+        q = 0
+        for js in waves:
             ds: List[Decision] = []
-            for b, j in enumerate(js):
-                p = int(win[wv, b])
+            for j in js:
+                p = win[q]
                 preds = inst._preds[j]
                 if len(preds) > 1:
                     preds = sorted(preds, key=lambda i: (aft_l[i], i))
@@ -1037,23 +1062,20 @@ class PallasBackend(CandidateEvaluator):
                     src = proc_l[i]
                     if src == p:
                         continue
-                    r = int(bestr[wv, b, k, p])
-                    lids, robj = inst._src_layouts[src].route_meta[p][r]
+                    lids, robj = inst._src_layouts[src].route_meta[p][
+                        bestr[q][k]]
                     msgs.append((i, robj,
-                                 [(lids[h], float(lst[wv, b, k, h, p]),
-                                   float(lft[wv, b, k, h, p]))
+                                 [(lids[h], lst[q][k][h], lft[q][k][h])
                                   for h in range(len(lids))]))
                 track = want_bound and not inst._is_exit[j]
                 if track:
-                    ca = tuple(float(x) for x in ca_all[wv, b, :P])
-                    cb = tuple(float(x) for x in cb_all[wv, b, :P])
+                    ca = tuple(ca_all[q])
+                    cb = tuple(cb_all[q])
                     contrib = self.crossing(p, ca, cb, alpha)
                 else:
                     ca = cb = None
                     contrib = _INF
-                d: Decision = (p, float(est[wv, b, p]),
-                               float(eft[wv, b, p]), msgs, ca, cb,
-                               contrib)
+                d: Decision = (p, est[q], eft[q], msgs, ca, cb, contrib)
                 if commit:
                     # f64 host mirrors in lockstep, as on the wave path
                     self._commit_host(j, d[0], d[1], d[2], d[3])
@@ -1063,6 +1085,7 @@ class PallasBackend(CandidateEvaluator):
                     proc_l[j] = p
                     aft_l[j] = d[2]
                 ds.append(d)
+                q += 1
             out.append(ds)
         return out
 
@@ -1088,25 +1111,26 @@ class PallasBackend(CandidateEvaluator):
         # mirrors; any later per-wave launch re-uploads first
         self._state_dirty = True
         with tracing.span("repro.backend.decode"):
-            return self._decode_scan(waves, outs, self.alpha, True,
-                                     self.want_bound)
+            won = _gather_winners(tuple(o[None] for o in outs), waves, 1,
+                                  self.inst.P)
+            return self._decode_scan(waves, tuple(x[0] for x in won),
+                                     self.alpha, True, self.want_bound)
 
     def supports_plan_sweep(self) -> bool:
         return _use_scan()
 
     def evaluate_plan_sweep(self, waves: Sequence[Sequence[int]],
                             alphas: Sequence[float], period: float,
-                            timeout: Optional[float] = None
-                            ) -> List[List[List[Decision]]]:
+                            timeout: Optional[float] = None) -> PlanSweep:
         """The (A, B) fused sweep: one ``vmap``-ed scan dispatch
-        evaluates every alpha's whole schedule (module docstring).
-        Decodes each alpha against its own local aft/proc arrays — run
-        state is never committed."""
+        evaluates every alpha's whole schedule (module docstring).  The
+        fetch is cut at once to each real decision's winner row
+        (:func:`_gather_winners`), which gives every alpha's EFTs; an
+        alpha's decisions are decoded only when asked for, against
+        per-alpha locals — run state is never committed."""
         alphas = list(alphas)
-        if not alphas:
-            return []
-        if not waves:
-            return [[] for _ in alphas]
+        if not alphas or not waves:
+            return PlanSweep(np.zeros((len(alphas), 0)), lambda a: [])
         t0 = time.monotonic()
         outs = self._scan_dispatch(waves, alphas)
         if timeout is not None:
@@ -1115,6 +1139,11 @@ class PallasBackend(CandidateEvaluator):
             if elapsed > budget:
                 raise WaveTimeoutError(0, elapsed, budget)
         with tracing.span("repro.backend.decode"):
-            return [self._decode_scan(waves, tuple(o[ai] for o in outs),
-                                      alpha, False, True)
-                    for ai, alpha in enumerate(alphas)]
+            won = _gather_winners(outs, waves, len(alphas), self.inst.P)
+
+        def decode(a: int) -> List[List[Decision]]:
+            tracing.count("backend.alphas_decoded")
+            with tracing.span("repro.backend.decode"):
+                return self._decode_scan(waves, tuple(x[a] for x in won),
+                                         alphas[a], False, True)
+        return PlanSweep(won[2], decode)

@@ -70,13 +70,14 @@ consistency conditions (same alpha/period/queue prefix).
 """
 from __future__ import annotations
 
-import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+import functools
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from .. import tracing
 from .backends import CandidateEvaluator, backend_class, resolve_backend_name
+from .backends.base import PlanSweep
 from .faults import (DOWN_COMP, INFEASIBLE_EFT, FaultSpec,
                      InfeasibleScheduleError)
 from .graph import SPG
@@ -155,20 +156,129 @@ DecisionRecord = Tuple[int, int, float, float, list, Optional[tuple],
                        Optional[tuple], int]
 
 
-@dataclasses.dataclass
 class DecisionTrace:
     """Memoized decision sequence of one :meth:`CompiledInstance._run`.
 
     Replayable: committing ``records[:k]`` reconstructs the exact engine
     state after the first ``k`` dequeues, so an update whose first ``k``
     decisions are provably unchanged re-simulates only positions ``k..n``.
+
+    A fused sweep's trace (:meth:`SweepSchedules.trace`) holds ``queue``,
+    ``alpha``, ``period`` and ``want_bound`` at once and builds its
+    ``records`` from ``source`` on first access.
     """
 
-    queue: Tuple[int, ...]
-    alpha: float
-    period: float
-    want_bound: bool
-    records: List[DecisionRecord]
+    __slots__ = ("queue", "alpha", "period", "want_bound", "_records",
+                 "_source")
+
+    def __init__(self, queue: Tuple[int, ...], alpha: float, period: float,
+                 want_bound: bool,
+                 records: Optional[List[DecisionRecord]] = None,
+                 source: Optional[Callable[[], List[DecisionRecord]]] = None
+                 ) -> None:
+        self.queue = queue
+        self.alpha = alpha
+        self.period = period
+        self.want_bound = want_bound
+        self._records = records
+        self._source = source
+
+    @property
+    def records(self) -> List[DecisionRecord]:
+        # the source is read before the records: a reader racing the
+        # first build either still holds the source (whose build
+        # publishes once) or finds the records already set
+        source = self._source
+        records = self._records
+        if records is None:
+            records = self._records = source()
+            self._source = None
+        return records
+
+
+class SweepSchedules(Sequence[Tuple[Schedule, float, DecisionTrace]]):
+    """What :meth:`CompiledInstance.schedule_sweep` returns.
+
+    ``makespans[a]`` is alpha ``a``'s makespan, read off the backend's
+    winner EFTs for every alpha at once (the same float as that alpha's
+    ``Schedule.makespan``).  Item ``a`` is alpha ``a``'s ``(Schedule,
+    bound, DecisionTrace)``, decoded and assembled when first indexed,
+    then kept; :meth:`trace` is alpha ``a``'s trace, whose records build
+    that item when first read.  Two threads building one alpha at once
+    each build it, and the first to finish publishes it for both.
+    """
+
+    def __init__(self, inst: "CompiledInstance", queue: Sequence[int],
+                 waves: List[List[int]], alphas: Sequence[float],
+                 period: float, swept: PlanSweep) -> None:
+        self._inst = inst
+        self._queue = tuple(queue)
+        self._waves = waves
+        self.alphas = list(alphas)
+        self._period = period
+        self._swept = swept
+        # each alpha's Schedule.finish, all at once: tasks outside the
+        # queue finish at 0.0 as in the assembled schedule (the initial
+        # only keeps a graph with no task from raising)
+        finish = np.zeros((len(self.alphas), inst.n))
+        finish[:, list(queue)] = swept.eft
+        self.makespans = finish.max(axis=1, initial=-_INF)
+        self._items: Dict[int, Tuple[Schedule, float,
+                                     List[DecisionRecord]]] = {}
+
+    def __len__(self) -> int:
+        return len(self.alphas)
+
+    def __getitem__(self, a: int) -> Tuple[Schedule, float, DecisionTrace]:
+        a = range(len(self.alphas))[a]
+        s, bound, records = self._item(a)
+        return s, bound, DecisionTrace(self._queue, self.alphas[a],
+                                       self._period, True, records)
+
+    def trace(self, a: int) -> DecisionTrace:
+        a = range(len(self.alphas))[a]
+        return DecisionTrace(self._queue, self.alphas[a], self._period,
+                             True, source=functools.partial(self._records, a))
+
+    def _records(self, a: int) -> List[DecisionRecord]:
+        return self._item(a)[2]
+
+    def _item(self, a: int) -> Tuple[Schedule, float, List[DecisionRecord]]:
+        item = self._items.get(a)
+        if item is None:
+            item = self._items.setdefault(a, self._build(a))
+        return item
+
+    def _build(self, a: int) -> Tuple[Schedule, float, List[DecisionRecord]]:
+        inst = self._inst
+        g, tg, names, n = inst.g, inst.tg, inst._link_names, inst.n
+        alpha = self.alphas[a]
+        per_wave = self._swept.decode(a)
+        # decisions to plan objects, for the alpha read
+        with tracing.span("repro.engine.assemble"):
+            messages: Dict[Tuple[int, int], MessagePlacement] = {}
+            records: List[DecisionRecord] = []
+            bound = _INF
+            procs = np.full(n, -1, dtype=np.int64)
+            ast_ = np.zeros(n)
+            aft_ = np.zeros(n)
+            bid = 0
+            for wave_js, decisions in zip(self._waves, per_wave):
+                for j, (p, est, eft, msgs, ca, cb, contrib) in zip(
+                        wave_js, decisions):
+                    for (i, route, iv) in msgs:
+                        messages[(i, j)] = MessagePlacement(
+                            (i, j), int(procs[i]), p, route,
+                            [(names[lid], s_, f) for (lid, s_, f) in iv])
+                    procs[j] = p
+                    ast_[j] = est
+                    aft_[j] = eft
+                    if contrib < bound:
+                        bound = contrib
+                    records.append((j, p, est, eft, msgs, ca, cb, bid))
+                bid += 1
+            return (Schedule(g, tg, procs, ast_, aft_, messages, alpha=alpha),
+                    bound, records)
 
 
 class CompiledInstance:
@@ -368,23 +478,23 @@ class CompiledInstance:
     def schedule_sweep(self, queue: Sequence[int], alphas: Sequence[float],
                        period: Optional[float] = None,
                        backend: Optional[str] = None,
-                       batch: Optional[int] = None
-                       ) -> List[Tuple[Schedule, float, DecisionTrace]]:
+                       batch: Optional[int] = None) -> SweepSchedules:
         """Schedule one queue under **every** alpha of a grid in a single
         device dispatch (the (A, B) fused sweep, DESIGN.md §5).
 
-        Per-alpha results are identical to ``len(alphas)`` independent
-        :meth:`schedule_traced` calls with ``want_bound=True`` — same
-        decisions, same recorded traces (so a later ``update()`` resumes
-        from them exactly like host-loop sweep traces), same
-        :class:`~.faults.InfeasibleScheduleError` on the first infeasible
-        (alpha, task) in sweep order.  Only valid when
+        Item ``a`` of the result is identical to an independent
+        :meth:`schedule_traced` call at ``alphas[a]`` with
+        ``want_bound=True`` — same decisions, same recorded trace (so a
+        later ``update()`` resumes from it exactly like a host-loop
+        sweep trace) — and is built only when read;
+        ``makespans`` holds every alpha's makespan at once (see
+        :class:`SweepSchedules`).  Raises the same
+        :class:`~.faults.InfeasibleScheduleError` on the first
+        infeasible (alpha, task) in sweep order.  Only valid when
         :meth:`sweep_supported`; fresh runs only (resume goes through the
         per-alpha host loop, which replays prefixes per trace).
         """
-        g, tg = self.g, self.tg
         preds_of = self._preds
-        names = self._link_names
         if period is None:
             period = self.default_period
         batch_cap = validate_batch(batch)
@@ -403,44 +513,19 @@ class CompiledInstance:
                             f"(Sec. 3.2)")
             for j in wave_js:
                 scheduled[j] = True
-        faulted = self.faults is not None
         swept = be.evaluate_plan_sweep(waves, list(alphas), period,
                                        timeout=self.wave_timeout)
-        # decisions to plan objects: one span over every alpha
-        with tracing.span("repro.engine.assemble"):
-            out: List[Tuple[Schedule, float, DecisionTrace]] = []
-            for alpha, per_wave in zip(alphas, swept):
-                messages: Dict[Tuple[int, int], MessagePlacement] = {}
-                records: List[DecisionRecord] = []
-                bound = _INF
-                procs = np.full(self.n, -1, dtype=np.int64)
-                ast_ = np.zeros(self.n)
-                aft_ = np.zeros(self.n)
-                bid = 0
-                for wave_js, decisions in zip(waves, per_wave):
-                    for j, (p, est, eft, msgs, ca, cb, contrib) in zip(
-                            wave_js, decisions):
-                        if faulted and not eft < INFEASIBLE_EFT:
-                            raise InfeasibleScheduleError(j, eft,
-                                                          self.faults)
-                        for (i, route, iv) in msgs:
-                            messages[(i, j)] = MessagePlacement(
-                                (i, j), int(procs[i]), p, route,
-                                [(names[lid], s_, f)
-                                 for (lid, s_, f) in iv])
-                        procs[j] = p
-                        ast_[j] = est
-                        aft_[j] = eft
-                        if contrib < bound:
-                            bound = contrib
-                        records.append((j, p, est, eft, msgs, ca, cb, bid))
-                    bid += 1
-                self.n_decisions_simulated += len(records)
-                tr = DecisionTrace(tuple(queue), alpha, period, True,
-                                   records)
-                out.append((Schedule(g, tg, procs, ast_, aft_, messages,
-                                     alpha=alpha), bound, tr))
-            return out
+        if self.faults is not None:
+            # the *winner* is only reachable through a masked resource:
+            # no feasible placement exists for that task
+            bad = ~(swept.eft < INFEASIBLE_EFT)
+            if bad.any():
+                a, k = divmod(int(np.argmax(bad)), bad.shape[1])
+                raise InfeasibleScheduleError(queue[k],
+                                              float(swept.eft[a, k]),
+                                              self.faults)
+        self.n_decisions_simulated += len(alphas) * len(queue)
+        return SweepSchedules(self, queue, waves, alphas, period, swept)
 
     # ------------------------------------------------------------------
     def _run(self, queue: Sequence[int], alpha: float,

@@ -10,10 +10,11 @@
   spans opened inside it carry the same one; two threads keep separate
   stacks.
 * **Call sites** — a fresh HVLB_CC(B) ``submit`` on the pallas backend
-  records each span of the plan path once (the decode of every alpha is
-  one span per dispatch; ``repro.api.prepare`` once at each of its three
-  sites) and plans exactly as it does with the recorder off; the scan's
-  jitted functions name its XLA module.
+  records each span of the plan path once, but ``repro.backend.decode``
+  twice (the winners' rows of the one dispatch, then the decode of the
+  one alpha read, alpha*) and ``repro.api.prepare`` once at each of its
+  three sites, and plans exactly as it does with the recorder off; the
+  scan's jitted functions name its XLA module.
 """
 import glob
 from collections import Counter
@@ -278,10 +279,13 @@ def test_submit_records_each_span_of_the_plan_path(annotations):
     tracing.disable()
     assert on.backend == "pallas" and on.fallback is None
     # once each; prepare at its three sites: the session, its queue
-    # and its compiled instance
+    # and its compiled instance; decode for the dispatch and for the one
+    # alpha decoded
+    c = snap["counters"]
+    assert c["backend.alphas_decoded"] == 1
     assert {k: v["count"] for k, v in snap["spans"].items()} == \
-        {name: 3 if name == "repro.api.prepare" else 1
-         for name in PLAN_SPANS}
+        {name: {"repro.api.prepare": 3, "repro.backend.decode": 2}.get(
+            name, 1) for name in PLAN_SPANS}
     # every span of the request carries the root's id
     assert [n for n, _ in annotations.made][0] == "repro.api.submit"
     assert len({s["rid"] for _, s in annotations.made}) == 1
@@ -290,7 +294,6 @@ def test_submit_records_each_span_of_the_plan_path(annotations):
     inner = sum(snap["spans"][n]["total_s"] for n in PLAN_SPANS[1:])
     assert sub["self_s"] == pytest.approx(sub["total_s"] - inner)
     assert 0 <= sub["self_s"] <= sub["total_s"]
-    c = snap["counters"]
     assert c["backend.launches"] == 1
     assert c["backend.h2d_bytes"] > 0 and c["backend.d2h_bytes"] > 0
     # the same plan as with the recorder off
