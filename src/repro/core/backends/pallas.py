@@ -924,6 +924,8 @@ class PallasBackend(CandidateEvaluator):
                 if tracing.enabled():
                     tracing.count("backend.h2d_bytes",
                                   sum(a.nbytes for a in self._scan_dev))
+                    tracing.count("backend.ct_bytes",
+                                  self._scan_dev[3].nbytes)
         return self._scan_dev
 
     def _scan_inputs(self, waves: Sequence[Sequence[int]]) -> tuple:
@@ -1027,6 +1029,11 @@ class PallasBackend(CandidateEvaluator):
                               if isinstance(a, np.ndarray)))
             tracing.count("backend.d2h_bytes",
                           sum(a.nbytes for a in fetched))
+            # hop bodies the dispatch ran: every (padded) alpha, wave,
+            # slot, predecessor, route and hop
+            tracing.count("backend.hop_steps",
+                          max(Ap, 1) * Wp * Bp * self._K * self._R
+                          * self._H)
         return tuple(fetched)
 
     def _decode_scan(self, waves: Sequence[Sequence[int]], outs: tuple,

@@ -38,11 +38,45 @@ def _mlp_flops(cfg: ModelConfig, tokens: int) -> float:
     return 2 * tokens * cfg.d_model * cfg.d_ff * mult
 
 
-def _moe_flops(cfg: ModelConfig, tokens: int) -> float:
+def ep_expert_flops(cfg: ModelConfig, routed: int) -> float:
+    """Prefill FLOPs of the expert MLPs over ``routed`` (token, expert)
+    pairs: one expert task's, or a whole MoE layer's."""
     mult = 3 if cfg.mlp in ("swiglu", "geglu") else 2
-    expert = 2 * tokens * cfg.top_k * cfg.d_model * cfg.d_ff * mult
-    router = 2 * tokens * cfg.d_model * cfg.n_experts
-    return expert + router
+    return 2 * routed * cfg.d_model * cfg.d_ff * mult
+
+
+def _router_flops(cfg: ModelConfig, tokens: int) -> float:
+    return 2 * tokens * cfg.d_model * cfg.n_experts
+
+
+def _moe_flops(cfg: ModelConfig, tokens: int) -> float:
+    routed = tokens * cfg.top_k
+    return ep_expert_flops(cfg, routed) + _router_flops(cfg, tokens)
+
+
+def ep_attention_flops(cfg: ModelConfig, tokens: int, kv_len: int) -> float:
+    """Prefill FLOPs of one expert-parallel attention task: attention
+    over ``tokens`` queries against ``kv_len`` keys, plus the router."""
+    return _attn_flops(cfg, tokens, kv_len) + _router_flops(cfg, tokens)
+
+
+def ep_message_bytes(cfg: ModelConfig, tokens: int) -> float:
+    """Bytes of ``tokens`` activations in bf16 (a dispatch or combine
+    message)."""
+    return float(tokens * cfg.d_model * 2)
+
+
+def ep_expert_weight_bytes(cfg: ModelConfig) -> float:
+    """Bytes of one expert's MLP weights of one layer at int8 (the
+    weights an expert task off its home chip streams in)."""
+    mult = 3 if cfg.mlp in ("swiglu", "geglu") else 2
+    return float(mult * cfg.d_model * cfg.d_ff)
+
+
+def ep_kv_bytes(cfg: ModelConfig, kv_len: int) -> float:
+    """Bytes of one layer's KV cache over ``kv_len`` tokens in bf16 (the
+    cache an attention task off its rank's chip reads and writes back)."""
+    return float(kv_len * 2 * cfg.n_kv_heads * cfg.head_dim * 2)
 
 
 def _mamba1_flops(cfg: ModelConfig, tokens: int) -> float:

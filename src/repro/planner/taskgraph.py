@@ -14,10 +14,14 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import tracing
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core.graph import SPG
+from repro.core.topology import Topology
 
-from .cost_model import stage_graph_costs
+from .cost_model import (ep_attention_flops, ep_expert_flops,
+                         ep_expert_weight_bytes, ep_kv_bytes,
+                         ep_message_bytes, stage_graph_costs)
 
 
 def model_stage_graph(cfg: ModelConfig, shape: ShapeConfig,
@@ -121,4 +125,122 @@ def serving_query_graph(cfg: ModelConfig, shape: ShapeConfig,
             name=f"{cfg.name}-dsms-{n_queries}q")
     g.tpl.update(tpl)
     g.query_ops = query_ops
+    return g
+
+
+@tracing.traced("repro.planner.ep_graph")
+def ep_step_graph(cfg: ModelConfig, tokens: np.ndarray, kv_len: np.ndarray,
+                  n_microbatches: int, tg: Topology) -> SPG:
+    """One prefill step of an MoE model under expert parallelism on the
+    chips of ``tg``.
+
+    ``tokens[l, m, d, e]`` is the number of tokens of data-parallel rank
+    ``d``'s chunk in microbatch ``m`` that layer ``l``'s router sends to
+    expert ``e`` (each token goes to ``cfg.top_k`` experts, so a chunk's
+    row sums to ``chunk * top_k``); ``kv_len[m, d]`` is the keys that
+    chunk attends to (its prompt offset plus the chunk).  The layers may
+    be the first ``L <= cfg.n_layers`` of the model.
+
+    Per layer ``l`` and microbatch ``m``: one attention task per rank
+    (attention of the chunk against ``kv_len`` keys, plus the router;
+    ``cost_model.ep_attention_flops``) and one task per expert (its MLP
+    over the tokens routed to it; ``ep_expert_flops``).  Edge
+    ``attn(l, m, d) -> expert(l, m, e)`` carries the dispatch of
+    ``tokens[l, m, d, e]`` activations, edge ``expert(l, m, e) ->
+    attn(l + 1, m, d)`` their combine (``ep_message_bytes``, bf16).
+    Weights are FLOPs, volumes bytes.  Every edge is present, with
+    volume 0 where no token is routed.
+
+    Residency: rank ``d``'s KV cache lives on chip ``d mod P`` and
+    expert ``e``'s weights on chip ``e mod P`` (one rank and one expert
+    a chip when both number ``P``).  A task runs on its home chip in
+    ``FLOPs / rate``; on any other chip ``p`` it also streams what it
+    needs from home, ``bytes / tg.route_speed(home, p)``: an attention
+    task its layer's KV cache (``ep_kv_bytes`` of ``kv_len``), an expert
+    task its weights (``ep_expert_weight_bytes``).  That time is the
+    executing chip's; it takes no link in the contention model.  So the
+    graph carries ``comp_matrix`` (tasks x chips), fixed to ``tg``'s
+    rates: to place the step with other rates, build it again on that
+    topology.
+
+    The returned SPG carries ``ep_tasks``: task id -> ``(layer,
+    microbatch, kind, index)``, kind ``"attn"`` (index: the rank) or
+    ``"expert"`` (index: the expert), and ``ep_home``: each task's home
+    chip.  Task ids run layer-major, then microbatch, then the ranks'
+    attention tasks, then the experts.
+    """
+    tokens = np.asarray(tokens)
+    kv_len = np.asarray(kv_len)
+    if tokens.ndim != 4:
+        raise ValueError(f"tokens must be (layers, microbatches, ranks, "
+                         f"experts), got shape {tokens.shape}")
+    L, M, D, E = tokens.shape
+    if M != n_microbatches:
+        raise ValueError(f"tokens hold {M} microbatches, expected "
+                         f"{n_microbatches}")
+    if E != cfg.n_experts or not 0 < L <= cfg.n_layers:
+        raise ValueError(f"tokens of {L} layers x {E} experts do not fit "
+                         f"{cfg.name} ({cfg.n_layers} layers, "
+                         f"{cfg.n_experts} experts)")
+    if kv_len.shape != (M, D):
+        raise ValueError(f"kv_len must have shape {(M, D)}, got "
+                         f"{kv_len.shape}")
+    if not np.issubdtype(tokens.dtype, np.integer) or (tokens < 0).any():
+        raise ValueError("tokens must be non-negative integers")
+    routed = tokens.sum(axis=3)
+    chunk, rem = divmod(int(routed.flat[0]), cfg.top_k)
+    if rem or (routed != chunk * cfg.top_k).any():
+        raise ValueError(f"every chunk must route the same number of "
+                         f"tokens to {cfg.top_k} experts each")
+    if (kv_len < chunk).any():
+        raise ValueError(f"kv_len must be at least the chunk ({chunk})")
+
+    def attn(l: int, m: int, d: int) -> int:
+        return (l * M + m) * (D + E) + d
+
+    def expert(l: int, m: int, e: int) -> int:
+        return (l * M + m) * (D + E) + D + e
+
+    P = tg.n_procs
+    n = L * M * (D + E)
+    weights = np.zeros(n)
+    resident = np.zeros(n)          # bytes a task streams in off home
+    home = np.zeros(n, dtype=np.int64)
+    tpl: Dict[Tuple[int, int], float] = {}
+    ep_tasks: Dict[int, Tuple[int, int, str, int]] = {}
+    w_bytes = ep_expert_weight_bytes(cfg)
+    for l in range(L):
+        for m in range(M):
+            for d in range(D):
+                t = attn(l, m, d)
+                weights[t] = ep_attention_flops(cfg, chunk,
+                                                int(kv_len[m, d]))
+                resident[t] = ep_kv_bytes(cfg, int(kv_len[m, d]))
+                home[t] = d % P
+                ep_tasks[t] = (l, m, "attn", d)
+            for e in range(E):
+                t = expert(l, m, e)
+                weights[t] = ep_expert_flops(cfg,
+                                             int(tokens[l, m, :, e].sum()))
+                resident[t] = w_bytes
+                home[t] = e % P
+                ep_tasks[t] = (l, m, "expert", e)
+                for d in range(D):
+                    vol = ep_message_bytes(cfg, int(tokens[l, m, d, e]))
+                    tpl[(attn(l, m, d), t)] = vol
+                    if l + 1 < L:
+                        tpl[(t, attn(l + 1, m, d))] = vol
+    # bytes/s from chip h to chip p; the diagonal is never read
+    speed = np.ones((P, P))
+    for h in range(P):
+        for p in range(P):
+            if p != h:
+                speed[h, p] = tg.route_speed(h, p)
+    away = np.where(home[:, None] == np.arange(P)[None, :], 0.0,
+                    resident[:, None] / speed[home])
+    comp = weights[:, None] / np.asarray(tg.rates, float)[None, :] + away
+    g = SPG(n=n, edges=list(tpl), weights=weights, tpl=tpl,
+            comp_matrix=comp, name=f"{cfg.name}-ep{E}-{L}L{M}mb")
+    g.ep_tasks = ep_tasks
+    g.ep_home = home
     return g

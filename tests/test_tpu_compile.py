@@ -15,7 +15,10 @@ Shapes:
   axis (bucketed to A=128);
 * the scan at exp6's multi-hop placement cell (qwen3-8b pipeline on
   ``tpu_slice_topology(8, 32, pods=2)``) with its 61-alpha sweep;
-* the per-wave ``pallas_call`` kernel at a P=16 wave.
+* the per-wave ``pallas_call`` kernel at a P=16 wave;
+* the fused 101-alpha sweep at the ``dbrx-ep16-mesh4x4-prefill`` cell's
+  padded shape: a 960-task expert-parallel step of dbrx-132b on the 4x4
+  ICI mesh (in-degree 16, two routes of up to 6 hops a pair).
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and test collection must not
@@ -162,3 +165,29 @@ def test_per_wave_kernel_compiles_p16(one_chip, chip_numerics, monkeypatch):
     runner = pb._compiled_run(B, K, R, H, P, L, True, False)
     compiled = _compile(one_chip, runner, args)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_scan_compiles_dbrx_ep16_mesh4x4(one_chip, chip_numerics,
+                                          monkeypatch):
+    """15 layers x 2 microbatches x (16 attention + 16 expert tasks) on
+    the 4x4 mesh: (W, B, K, R, H, Pp, Lp, Np, Ep, A) = (64, 16, 16, 2,
+    6, 16, 128, 1024, 16384, 128), within the chip's 16 GB."""
+    from repro.configs import ARCHS
+    from repro.planner import ep_step_graph, tpu_mesh_topology
+
+    tokens = np.full((15, 2, 16, 16), 512)        # 2048 tokens x top-4
+    kv = 2048 * (1 + np.arange(32).reshape(2, 16) % 16)
+    tg = tpu_mesh_topology(4, 4, degraded={5: 0.8})
+    g = ep_step_graph(ARCHS["dbrx-132b"], tokens, kv, 2, tg)
+    r = rank_matrix(g, tg)
+    inst = CompiledInstance(g, tg, rank=r)
+    q = priority_queue(hprv_b(g, tg, r), r.mean(1))
+    alphas = [k * 0.05 for k in range(101)]       # plan_placement's grid
+    key, args = _capture(
+        monkeypatch, "_scan_run",
+        lambda: inst.schedule_sweep(q, alphas, backend="pallas"))
+    assert key == (64, 16, 16, 2, 6, 16, 128, 1024, 16384, 128, True)
+    mem = _compile(one_chip, pb._scan_run(*key), args).memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < 16e9
