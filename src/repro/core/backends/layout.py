@@ -33,9 +33,10 @@ import numpy as np
 if TYPE_CHECKING:                                   # pragma: no cover
     from ..engine import CompiledInstance
 
-__all__ = ["LANE", "SUBLANE_F32", "SrcLayout", "edge_ct", "ensure_ct_table",
-           "pad_dim", "padded_edge_ct", "padded_src_tensors", "src_layout",
-           "stacked_edge_ct", "stacked_src_tensors"]
+__all__ = ["CT_COL0", "CT_NEG", "CT_ZERO", "LANE", "SUBLANE_F32",
+           "SrcLayout", "bucket", "edge_ct", "ensure_ct_table",
+           "factored_edge_ct", "pad_dim", "padded_edge_ct",
+           "padded_src_tensors", "src_layout", "stacked_src_tensors"]
 
 _NEG_INF = float("-inf")
 
@@ -286,35 +287,68 @@ def stacked_src_tensors(inst: "CompiledInstance", R: int, H: int,
     return masks, valid, nhops
 
 
-def stacked_edge_ct(inst: "CompiledInstance", R: int, H: int, Pp: int,
-                    Ep: int) -> np.ndarray:
-    """Eq. 15 CTML of **every** edge from **every** source, stacked to
-    ``(Ep, P + 1, R, H, Pp)`` for the scan path's dynamic double gather
-    ``ct[edge_index, proc_of[pred]]``.
+# codes of the factored CTML table's index (:func:`factored_edge_ct`):
+# ``CT_NEG`` reads -inf (padding), ``CT_ZERO`` reads 0.0 (the src lane's
+# fake route 0), and ``CT_COL0 + u`` reads quotient column ``u``
+CT_NEG, CT_ZERO, CT_COL0 = 0, 1, 2
 
-    ``Ep >= E + 1``: rows ``>= E`` and source plane ``P`` are the
-    padding-predecessor convention (``-inf`` everywhere — a no-op of the
-    Eq. 13-14 max algebra; the pad source's fake route 0 is validated in
-    :func:`stacked_src_tensors`).  Built from the per-src all-edge
-    tables (:func:`ensure_ct_table`), so the floats are bit-identical to
-    the per-wave path's :func:`padded_edge_ct` views.
+
+def bucket(b: int) -> int:
+    """Smallest power of two >= b (bounds compiled kernel variants)."""
+    n = 1
+    while n < b:
+        n *= 2
+    return n
+
+
+def factored_edge_ct(inst: "CompiledInstance", R: int, H: int, Pp: int,
+                     Ep: int, dtype) -> Tuple[np.ndarray, np.ndarray]:
+    """Eq. 15 CTML of **every** edge from **every** source, factored by
+    each source's distinct hop speeds, for the scan path's
+    ``(Ep, P + 1, R, H, Pp)`` table (expanded on the device by
+    ``pallas.repro_ct_expand``).
+
+    The CTML of edge ``e`` from ``s`` at ``(r, h)`` toward lane ``p`` is
+    ``quant(tpl[e, s] / spd[s, r, h, p])``, and a source's hops share a
+    few speeds, so the table holds one quotient per (edge, source,
+    distinct speed).  Returns
+
+      * ``q`` — ``(Ep, P + 1, Uc)`` in ``dtype``: ``q[e, s, u]`` is edge
+        ``e``'s quotient at the ``u``-th smallest speed of ``s``; the same
+        float64 division and ``np.rint``/``np.ceil`` as
+        :func:`ensure_ct_table`, then the cast, so each float is the one
+        the per-wave path's :func:`padded_edge_ct` gives.  Edge rows
+        ``>= E``, source plane ``P`` and unused columns are ``-inf``;
+      * ``idx`` — ``(P + 1, R, H, Pp)`` int32 codes (``CT_NEG``,
+        ``CT_ZERO``, ``CT_COL0 + u``): hop/route/lane padding and the
+        pad source plane read ``-inf``, the src lane's fake route 0 reads
+        0.0 (``-inf`` on the pad edge rows, as the table had it).
+
+    ``Uc`` is the largest count of distinct speeds of any source,
+    bucketed (:func:`bucket`) but never above the ``R * H * Pp``
+    positions a source has, so ``q`` is never larger than the table.
     """
+    P = inst.P
     E = len(inst._edge_index)
     assert Ep >= E + 1
-    full = np.full((Ep, inst.P + 1, R, H, Pp), _NEG_INF)
-    for s in range(inst.P):
-        lay = src_layout(inst, s)
-        if E == 0:
-            continue
-        tab = lay.ct_table
-        if tab is None:
-            tab = ensure_ct_table(inst, lay)
-        if lay.R == 1:
-            full[:E, s, 0, :lay.H, :lay.P] = tab         # (E, H, P)
-        else:
-            full[:E, s, :lay.R, :lay.H, :lay.P] = \
-                tab.transpose(0, 2, 3, 1)                # (E, P, R, H)
-    return full
+    lays = [src_layout(inst, s) for s in range(P)]
+    speeds = [np.unique(lay.spd[~lay.pad]) for lay in lays]
+    Uc = min(bucket(max([1] + [len(u) for u in speeds])), R * H * Pp)
+    q = np.full((Ep, P + 1, Uc), _NEG_INF)
+    idx = np.full((P + 1, R, H, Pp), CT_NEG, dtype=np.int32)
+    mode = inst._ctml_mode
+    for s, (lay, u) in enumerate(zip(lays, speeds)):
+        ct = inst._tpl_matrix[:, s, None] / u                # (E, U_s)
+        if mode == "round":
+            np.rint(ct, out=ct)
+        elif mode == "ceil":
+            np.ceil(ct, out=ct)
+        q[:E, s, :len(u)] = ct
+        code = CT_COL0 + np.searchsorted(u, lay.spd)         # (P, R, H)
+        code[lay.pad] = CT_NEG
+        code[s, 0, :] = CT_ZERO      # fake route: final LFT == aft_i
+        idx[s, :lay.R, :lay.H, :lay.P] = code.transpose(1, 2, 0)
+    return q.astype(dtype), idx
 
 
 def padded_edge_ct(inst: "CompiledInstance", lay: SrcLayout, i: int, j: int,

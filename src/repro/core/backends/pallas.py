@@ -80,7 +80,10 @@ jitted ``lax.scan`` dispatch (``evaluate_plan``).  The engine emits the
 complete level-batched plan up front (``engine.plan_waves``); the host
 stages stacked per-wave inputs (task ids, predecessor ids + edge
 indices, exit/real flags) plus the all-source route tensors
-(``layout.stacked_src_tensors`` / ``stacked_edge_ct``), and the scan
+(``layout.stacked_src_tensors``) and the all-edge CTML table, uploaded
+factored by each source's distinct hop speeds
+(``layout.factored_edge_ct``) and expanded on the device
+(:func:`repro_ct_expand`, the same bits), and the scan
 body — pure ``jnp``, the exact op-for-op algebra of the per-wave kernel
 — carries ``(link_free, proc_free, loads, loads/period, BP, aft,
 proc_of)`` wave to wave, sorting each decision's predecessors by the
@@ -121,9 +124,9 @@ from jax.experimental import pallas as pl
 from ... import tracing
 from .base import CandidateEvaluator, Decision, PlanSweep
 from ..faults import WaveTimeoutError
-from .layout import (LANE, SUBLANE_F32, pad_dim, padded_edge_ct,
-                     padded_src_tensors, src_layout, stacked_edge_ct,
-                     stacked_src_tensors)
+from .layout import (CT_COL0, CT_ZERO, LANE, SUBLANE_F32, bucket,
+                     factored_edge_ct, pad_dim, padded_edge_ct,
+                     padded_src_tensors, src_layout, stacked_src_tensors)
 
 _INF = float("inf")
 _NEG_INF = float("-inf")
@@ -200,14 +203,6 @@ def _x64(f32: bool):
     jnp and jit canonicalize float64 inputs (and the kernel trace) down
     to float32.  The f32 path needs no switch."""
     return contextlib.nullcontext() if f32 else jax.enable_x64(True)
-
-
-def _bucket(b: int) -> int:
-    """Smallest power of two >= b (bounds compiled kernel variants)."""
-    n = 1
-    while n < b:
-        n *= 2
-    return n
 
 
 def _batch_kernel(alpha_ref, period_ref, aft_ref, ct_ref, masks_ref,
@@ -621,6 +616,26 @@ def _scan_run(W: int, B: int, K: int, R: int, H: int, Pp: int, Lp: int,
     return run
 
 
+@jax.jit
+def repro_ct_expand(q: jax.Array, idx: jax.Array,
+                    n_edges: jax.Array) -> jax.Array:
+    """The scan's ``(Ep, P + 1, R, H, Pp)`` edge CTML table from its
+    factored upload (``layout.factored_edge_ct``): ``ct[e, s, r, h, p]``
+    is ``q[e, s, u]`` where ``idx[s, r, h, p]`` is ``CT_COL0 + u``,
+    ``-inf`` at ``CT_NEG``, and at ``CT_ZERO`` 0.0 on the ``n_edges``
+    real edge rows and ``-inf`` on the pad rows.  Selects of the
+    uploaded floats, so the table's bits are the host build's.  Its XLA
+    module is ``jit_repro_ct_expand`` on the device trace."""
+    Ep, S, U = q.shape
+    neg = jnp.array(_NEG_INF, q.dtype)
+    ct = jnp.full((Ep, S) + idx.shape[1:], neg)
+    zero = jnp.where(jnp.arange(Ep) < n_edges, jnp.zeros((), q.dtype), neg)
+    ct = jnp.where(idx == CT_ZERO, zero[:, None, None, None, None], ct)
+    for u in range(U):
+        ct = jnp.where(idx == CT_COL0 + u, q[:, :, u, None, None, None], ct)
+    return ct
+
+
 def _gather_winners(outs: tuple, waves: Sequence[Sequence[int]], A: int,
                     P: int) -> tuple:
     """Each real decision's row of fetched scan outputs at its winning
@@ -690,8 +705,8 @@ class PallasBackend(CandidateEvaluator):
         # scan-path consts: bucketed task/edge axes for the carried
         # aft/proc arrays and the stacked all-edge CT table; the device
         # stacks themselves are built lazily on the first plan dispatch
-        self._Np = _bucket(inst.n)
-        self._Ep = _bucket(len(inst._edge_index) + 1)
+        self._Np = bucket(inst.n)
+        self._Ep = bucket(len(inst._edge_index) + 1)
         self._scan_dev: Optional[tuple] = None
         self._scan_in_cache: "OrderedDict[tuple, tuple]" = OrderedDict()
         # instrumentation (read by benchmarks/exp7 and the tests)
@@ -799,7 +814,7 @@ class PallasBackend(CandidateEvaluator):
             self._upload_state()
 
         B = len(js)
-        Bp = _bucket(B)
+        Bp = bucket(B)
         pad_ct, pad_masks, pad_valid, pad_nhops = self._pad
         cts, masks, valids, nhopss = [], [], [], []
         aft_rows = np.full((Bp, K), _NEG_INF)
@@ -903,29 +918,37 @@ class PallasBackend(CandidateEvaluator):
     # ----------------------------------------------- whole-schedule scan
     def _scan_tables(self) -> tuple:
         """Device-resident all-source/all-edge stacks for the scan's
-        dynamic gathers (built once per backend; a few MB at exp7
-        scale).  Task-indexed comp/ldet rows are padded to the bucketed
-        ``Np`` (pad rows are never gathered — task ids are < n)."""
+        dynamic gathers (built once per backend).  The edge CTML table
+        is uploaded factored by each source's distinct hop speeds and
+        expanded on the device (:func:`repro_ct_expand`).  Task-indexed
+        comp/ldet rows are padded to the bucketed ``Np`` (pad rows are
+        never gathered — task ids are < n)."""
         if self._scan_dev is None:
             with tracing.span("repro.backend.tables"):
                 inst = self.inst
                 n, Np = inst.n, self._Np
                 masks, valid, nhops = stacked_src_tensors(
                     inst, self._R, self._H, self._Pp, self._Lp)
-                ct = stacked_edge_ct(inst, self._R, self._H, self._Pp,
-                                     self._Ep)
+                q, idx = factored_edge_ct(inst, self._R, self._H, self._Pp,
+                                          self._Ep, self._np_dtype)
                 comp = np.zeros((Np, self._Pp))
                 comp[:n] = self._comp_rows
                 ldet = np.ones((Np, self._Pp))
                 ldet[:n] = self._ldet_rows
-                self._scan_dev = tuple(
+                masks, valid, nhops, q, comp, ldet = (
                     self._to_dev(x)
-                    for x in (masks, valid, nhops, ct, comp, ldet))
+                    for x in (masks, valid, nhops, q, comp, ldet))
+                idx = jnp.asarray(idx)
+                with _x64(self._f32):
+                    ct = repro_ct_expand(
+                        q, idx, np.int32(len(inst._edge_index)))
+                self._scan_dev = (masks, valid, nhops, ct, comp, ldet)
                 if tracing.enabled():
-                    tracing.count("backend.h2d_bytes",
-                                  sum(a.nbytes for a in self._scan_dev))
-                    tracing.count("backend.ct_bytes",
-                                  self._scan_dev[3].nbytes)
+                    tracing.count("backend.h2d_bytes", sum(
+                        a.nbytes
+                        for a in (masks, valid, nhops, q, idx, comp, ldet)))
+                    tracing.count("backend.ct_bytes", q.nbytes + idx.nbytes)
+                    tracing.count("backend.ct_columns", q.shape[2])
         return self._scan_dev
 
     def _scan_inputs(self, waves: Sequence[Sequence[int]]) -> tuple:
@@ -941,8 +964,8 @@ class PallasBackend(CandidateEvaluator):
             return cached
         inst = self.inst
         K, Ep = self._K, self._Ep
-        Wp = _bucket(len(waves))
-        Bp = _bucket(max(len(w) for w in waves))
+        Wp = bucket(len(waves))
+        Bp = bucket(max(len(w) for w in waves))
         task = np.zeros((Wp, Bp), np.int32)
         real = np.zeros((Wp, Bp))
         pred = np.zeros((Wp, Bp, K), np.int32)
@@ -1001,7 +1024,7 @@ class PallasBackend(CandidateEvaluator):
                 Ap = 0
                 a_arg = np.asarray(self.alpha, dtype=dt)
             else:
-                Ap = _bucket(len(alphas))
+                Ap = bucket(len(alphas))
                 a_arg = np.asarray(
                     list(alphas) + [alphas[-1]] * (Ap - len(alphas)),
                     dtype=dt)

@@ -278,7 +278,8 @@ def test_placement_plan_carries_the_plan():
 def test_scan_counts_hop_steps_and_ct_bytes():
     """``backend.hop_steps`` is padded alphas x waves x slots x
     predecessors x routes x hops of the dispatch; ``backend.ct_bytes``
-    the edge CTML table's share of ``backend.h2d_bytes``."""
+    the edge CTML table's share of ``backend.h2d_bytes``, uploaded
+    factored by the distinct hop speeds (``backend.ct_columns``)."""
     pytest.importorskip("jax")
     tok, kv = step()
     tg = tpu_mesh_topology(2, 2)
@@ -294,6 +295,9 @@ def test_scan_counts_hop_steps_and_ct_bytes():
     # 5 waves of up to 8 (the microbatches interleave), padded to 8 x 8;
     # in-degree 4; 2 routes of up to 2 hops on a 2x2 mesh
     assert c["backend.hop_steps"] == 4 * 8 * 8 * 4 * 2 * 2
-    # (Ep, P + 1, R, H, P) float64: 64 edges + 1 pad bucket to 128
-    assert c["backend.ct_bytes"] == 128 * 5 * 2 * 2 * 4 * 8
+    # the factored upload: every link of the mesh runs at one speed, so
+    # one quotient column, (Ep, P + 1, 1) float64 with 64 edges + 1 pad
+    # bucketed to 128, and the (P + 1, R, H, P) int32 column index
+    assert c["backend.ct_columns"] == 1
+    assert c["backend.ct_bytes"] == 128 * 5 * 1 * 8 + 5 * 2 * 2 * 4 * 4
     assert c["backend.h2d_bytes"] > c["backend.ct_bytes"]

@@ -18,7 +18,8 @@ Shapes:
 * the per-wave ``pallas_call`` kernel at a P=16 wave;
 * the fused 101-alpha sweep at the ``dbrx-ep16-mesh4x4-prefill`` cell's
   padded shape: a 960-task expert-parallel step of dbrx-132b on the 4x4
-  ICI mesh (in-degree 16, two routes of up to 6 hops a pair).
+  ICI mesh (in-degree 16, two routes of up to 6 hops a pair), and the
+  device expansion of its factored edge CTML table.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and test collection must not
@@ -167,11 +168,10 @@ def test_per_wave_kernel_compiles_p16(one_chip, chip_numerics, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_scan_compiles_dbrx_ep16_mesh4x4(one_chip, chip_numerics,
-                                          monkeypatch):
-    """15 layers x 2 microbatches x (16 attention + 16 expert tasks) on
-    the 4x4 mesh: (W, B, K, R, H, Pp, Lp, Np, Ep, A) = (64, 16, 16, 2,
-    6, 16, 128, 1024, 16384, 128), within the chip's 16 GB."""
+@pytest.fixture(scope="module")
+def dbrx_ep16():
+    """15 layers x 2 microbatches x (16 attention + 16 expert tasks) of
+    dbrx-132b on the 4x4 mesh, and its HVLB_CC(B) queue."""
     from repro.configs import ARCHS
     from repro.planner import ep_step_graph, tpu_mesh_topology
 
@@ -180,8 +180,15 @@ def test_scan_compiles_dbrx_ep16_mesh4x4(one_chip, chip_numerics,
     tg = tpu_mesh_topology(4, 4, degraded={5: 0.8})
     g = ep_step_graph(ARCHS["dbrx-132b"], tokens, kv, 2, tg)
     r = rank_matrix(g, tg)
-    inst = CompiledInstance(g, tg, rank=r)
-    q = priority_queue(hprv_b(g, tg, r), r.mean(1))
+    return CompiledInstance(g, tg, rank=r), priority_queue(
+        hprv_b(g, tg, r), r.mean(1))
+
+
+def test_scan_compiles_dbrx_ep16_mesh4x4(one_chip, chip_numerics,
+                                          monkeypatch, dbrx_ep16):
+    """(W, B, K, R, H, Pp, Lp, Np, Ep, A) = (64, 16, 16, 2, 6, 16, 128,
+    1024, 16384, 128), within the chip's 16 GB."""
+    inst, q = dbrx_ep16
     alphas = [k * 0.05 for k in range(101)]       # plan_placement's grid
     key, args = _capture(
         monkeypatch, "_scan_run",
@@ -191,3 +198,22 @@ def test_scan_compiles_dbrx_ep16_mesh4x4(one_chip, chip_numerics,
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < 16e9
+
+
+def test_ct_expand_compiles_dbrx_ep16_mesh4x4(one_chip, chip_numerics,
+                                              dbrx_ep16):
+    """The edge CTML table's expansion at the same cell: one quotient
+    column (every ICI link at one speed) up, the (Ep, P + 1, R, H, Pp)
+    float32 table out."""
+    from repro.core.backends.layout import factored_edge_ct
+
+    inst, _ = dbrx_ep16
+    be = pb.PallasBackend(inst)
+    q, idx = factored_edge_ct(inst, be._R, be._H, be._Pp, be._Ep,
+                              np.float32)
+    assert q.shape == (16384, 17, 1) and idx.shape == (17, 2, 6, 16)
+    mem = _compile(one_chip, pb.repro_ct_expand,
+                   (q, idx, np.int32(len(inst._edge_index)))
+                   ).memory_analysis()
+    assert mem.output_size_in_bytes == 16384 * 17 * 2 * 6 * 16 * 4
+    assert mem.argument_size_in_bytes < 2e6

@@ -309,18 +309,24 @@ def test_submit_records_each_span_of_the_plan_path(annotations):
 def test_fetch_counter_is_the_fetched_arrays(monkeypatch):
     """``backend.d2h_bytes`` counts what the one fetch of a dispatch
     returned, ``backend.h2d_bytes`` the host arrays staged for it plus
-    the uploads of the evaluator's set-up and constant tables."""
+    the uploads of the evaluator's set-up and constant tables, the edge
+    CTML table as uploaded: factored, and expanded on the device."""
     pytest.importorskip("jax")
     import repro.core.backends.pallas as pb
+    from repro.core.backends.layout import factored_edge_ct
 
-    fetched, staged = [], []
+    fetched, staged, ct = [], [], []
     dispatch = pb.PallasBackend._scan_dispatch
 
     def seen(self, waves, alphas):
         out = dispatch(self, waves, alphas)
         fetched.append(sum(a.nbytes for a in out))
+        q, idx = factored_edge_ct(self.inst, self._R, self._H, self._Pp,
+                                  self._Ep, self._np_dtype)
+        ct.append(q.nbytes + idx.nbytes)
+        tables = [a for k, a in enumerate(self._scan_dev) if k != 3]
         staged.append(sum(a.nbytes for a in self._pad) +
-                      sum(a.nbytes for a in self._scan_dev))
+                      sum(a.nbytes for a in tables) + ct[0])
         return out
 
     monkeypatch.setattr(pb.PallasBackend, "_scan_dispatch", seen)
@@ -329,6 +335,7 @@ def test_fetch_counter_is_the_fetched_arrays(monkeypatch):
     Scheduler(tg, policy=pol, backend="pallas").submit(g)
     c = tracing.snapshot()["counters"]
     assert c["backend.d2h_bytes"] == fetched[0]
+    assert c["backend.ct_bytes"] == ct[0]
     assert c["backend.h2d_bytes"] > staged[0]        # + the dispatch's
 
 
